@@ -35,11 +35,14 @@ pins the curve to the machine: on a single-core container threads
 time-share one CPU and real speedup is physically capped regardless of
 dispatch overlap.
 
-Run standalone it requests 8 virtual devices in-process BEFORE jax
-backend init; under ``benchmarks/run.py`` it is spawned as a subprocess
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for the
-same reason.  With fewer devices than shards the executor round-robins
-(degradation path — the curve flattens but every assert still holds).
+Run standalone it requests ``--devices`` (default 8) devices BEFORE jax
+backend init: virtual ones on CPU, while on an accelerator a host with
+fewer chips is an error (pass ``--devices`` to match it).  Under
+``benchmarks/run.py`` on CPU it is spawned as a subprocess with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for the same
+reason; on a chip ``run.py`` calls :func:`run` in its own process, which
+already holds the chips.  With fewer devices than shards the executor
+round-robins (the curve flattens but every assert still holds).
 
 By default each curve point runs in its OWN subprocess
 (``--no-isolate-points`` to disable): XLA's LLVM JIT pins ~dozens of
@@ -211,7 +214,9 @@ def _run_isolated(args, parts_list):
     """One subprocess per curve point (fresh XLA JIT state each), merged
     into a single report with the monotone guard applied at the end.
 
-    Each child is this module with a single-element ``--parts-list`` and
+    The parent never initializes a jax backend (a chip belongs to one
+    process at a time, and each child needs it).  Each child is this
+    module with a single-element ``--parts-list`` and
     ``--no-isolate-points``; its emit lines are passed through (header
     dropped) and its report's shard entry is merged.  The per-point
     speedup is the child's own in-process compiled-vs-sharded ratio.
@@ -333,9 +338,9 @@ def main():
     # request virtual devices BEFORE anything initializes a jax backend
     from repro.launch.mesh import ensure_host_devices
 
-    got = ensure_host_devices(args.devices)
+    got = ensure_host_devices(args.devices)  # raises on a short accelerator
     if got < args.devices:
-        print(f"# requested {args.devices} devices, got {got} (degrading)")
+        print(f"# requested {args.devices} CPU devices, got {got} (degrading)")
 
     print("name,us_per_call,derived")
     from benchmarks.common import traced

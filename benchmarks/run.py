@@ -15,13 +15,22 @@ import time
 import traceback
 
 
-def _run_shard_subprocess(trace_dir=None) -> None:
-    """bench_shard needs --xla_force_host_platform_device_count before
-    jax backend init; by the time the suite reaches it this process has
-    long been initialized with the real (single) device, so the shard
+def _run_shard(trace_dir=None) -> None:
+    """On an accelerator this process already holds the chips (a chip
+    belongs to one process at a time), so bench_shard runs in-process
+    over them.  On CPU it needs --xla_force_host_platform_device_count
+    before jax backend init; by the time the suite reaches it this
+    process has long been initialized with one CPU device, so the shard
     bench runs in a subprocess with the flag in its environment."""
-    from benchmarks import bench_shard
+    import jax
 
+    from benchmarks import bench_shard
+    from benchmarks.common import traced
+
+    if jax.default_backend() != "cpu":
+        with traced(trace_dir, "shard"):
+            bench_shard.run(out_path=bench_shard.ROOT_OUT)
+        return
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
@@ -51,6 +60,10 @@ def main() -> None:
     )
     args = ap.parse_args()
     only = set(args.only.split(","))
+
+    from repro.launch.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.time()
@@ -103,7 +116,7 @@ def main() -> None:
         # emit its BENCH_shard.json (scaling curve + balance + exactness)
         # at the repo root
         jobs.append(
-            ("shard", lambda: _run_shard_subprocess(args.trace_dir))
+            ("shard", lambda: _run_shard(args.trace_dir))
         )
     if "witness" in only:
         from benchmarks import bench_witness
@@ -136,8 +149,8 @@ def main() -> None:
     failures = []
     for name, fn in jobs:
         try:
-            # the shard job traces inside its own subprocess (the span
-            # capture must live where the devices do)
+            # the shard job traces where its devices live (a CPU
+            # subprocess, or this process on a chip)
             with traced(None if name == "shard" else args.trace_dir, name):
                 fn()
         except Exception as e:  # keep the suite going, report at the end

@@ -55,9 +55,9 @@ the target graph feed the decisions):
    grid is folded INTO the kernel as a ``lax.fori_loop`` over offset
    combinations, so a swept bucket is one launch, not ``n_sweep``.  With
    ``backend="pallas"`` the pairwise (``pw``) compare cube routes through
-   the ``kernels/intersect_count`` Pallas op (interpret mode off-TPU),
-   whose VMEM-budgeted ``block_rows`` tiling is derived from the same
-   bucket-ladder dims.
+   the ``kernels/intersect_count`` Pallas op (a Mosaic kernel on TPU,
+   interpret mode elsewhere), whose lane-dense tiling is derived from the
+   same bucket-ladder dims.
 
 6. **Execution** (:mod:`repro.core.executor`) — the bucket schedule
    (unique (strategy, bucket) groups, chunk widths, padded staging
@@ -121,11 +121,11 @@ C_COMPARE = 1.0
 # seeds whose best padded strategy exceeds this are decomposed into
 # per-branch work items (the paper's two-phase "deep tail" post-processing):
 # the level-1 frontier is expanded host-side and every branch is re-bucketed
-# by its OWN degrees at every level.  Sweeping this threshold
-# (EXPERIMENTS.md §Perf-mining M4) showed the bulk path's max-over-branches
-# padding loses even for mildly hub-adjacent seeds: 2^11 beat 2^21 by 30x on
-# scatter-gather — per-branch decomposition is the right default for ALL
-# deep work, with the bulk path kept for genuinely uniform low-degree seeds
+# by its OWN degrees at every level.  Sweeping this threshold showed the
+# bulk path's max-over-branches padding loses even for mildly hub-adjacent
+# seeds: 2^11 beat 2^21 by 30x on scatter-gather (CPU backend) — per-branch
+# decomposition is the right default for ALL deep work, with the bulk path
+# kept for genuinely uniform low-degree seeds
 BRANCH_DECOMP_COST = float(1 << 11)
 
 
@@ -166,8 +166,8 @@ def _pallas_pair_count(
     both padded neighbor tiles are broadcast to ``(rows, D)``; window
     bounds must be constant along the D axes (they anchor at seed or
     frontier stage times, never at the expansion element).  The Pallas op
-    picks its VMEM-budgeted ``block_rows`` from the static (d_a, d_b)
-    bucket dims and runs in interpret mode off-TPU.
+    picks its tiling from the static (d_a, d_b) bucket dims and runs in
+    interpret mode off-TPU.
     """
     from repro.kernels.intersect_count import intersect_count
 
@@ -583,7 +583,7 @@ class CompiledPattern:
         # LRU-capped: schedules pin their staging buffers, so a long-lived
         # session mining ever-fresh seed sets must not accumulate them.
         # `schedule_mode` picks the cache key:
-        #   "value" — (seed count, sha1 of seed values, bulk_only); hits
+        #   "value" — (seed count, sha1 of seed values, witness); hits
         #             replay the cached staging verbatim (sessions /
         #             sharded mines re-mining identical seed sets);
         #   "shape" — the pow2-padded launch profile (group strat/dims/
@@ -1347,13 +1347,16 @@ class CompiledPattern:
         specs: List[_GroupSpec],
         n_out: int,
         pad_rows: bool = False,
+        item_rows: bool = False,
     ) -> List[executor.BucketGroup]:
         """The staging half of a schedule build: chunk widths + padded
         host staging buffers for every analyzed group.  ``pad_rows=True``
         sizes each group's widths for its pow2-ceiled row count (the
         surplus rows scatter into the drop sentinel), making the widths
         canonical per shape profile — the launch-time half of shape-keyed
-        schedule reuse."""
+        schedule reuse.  ``item_rows=True`` (witness branch groups) makes
+        every branch row scatter into its own item's slot of an
+        ``n_out``-item accumulator instead of into its seed's."""
         groups: List[executor.BucketGroup] = []
         for gs in specs:
             widths = executor.chunk_widths(
@@ -1370,7 +1373,7 @@ class CompiledPattern:
                 gs.dst,
                 gs.st,
                 seg_vals=(
-                    gs.seed_of[gs.sel] if gs.branch else gs.sel
+                    gs.seed_of[gs.sel] if gs.branch and not item_rows else gs.sel
                 ).astype(np.int32),
                 fr=gs.fr if gs.branch else None,
                 frt=gs.frt if gs.branch else None,
@@ -1397,13 +1400,15 @@ class CompiledPattern:
 
     def _expand_branches(self, src, dst, st):
         """Host-side level-1 frontier expansion for hub seeds (numpy CSR
-        slices)."""
+        slices): per branch item its seed's index, node, time and edge id,
+        grouped by seed and in CSR row order within a seed."""
         fa = self.ir.frontiers[0]
         opn = fa.operand
         g = self.g
         indptr = g.out_indptr if opn.direction == "out" else g.in_indptr
         nbr = g.out_nbr if opn.direction == "out" else g.in_nbr
         tt = g.out_t if opn.direction == "out" else g.in_t
+        eid = g.out_eid if opn.direction == "out" else g.in_eid
         base = src if opn.node.name == "seed.src" else dst
         offs, lens = csr_row_offsets(indptr, base)
         item_seed = np.repeat(np.arange(len(src), dtype=np.int64), lens)
@@ -1415,12 +1420,12 @@ class CompiledPattern:
         for ref in fa.skip_eq:
             vals = src if ref.name == "seed.src" else dst
             ok &= fr != vals[item_seed]
-        return item_seed[ok], fr[ok], frt[ok].astype(np.int32)
+        return item_seed[ok], fr[ok], frt[ok].astype(np.int32), eid[offs][ok]
 
     def _build_schedule(
         self,
         seed_eids: np.ndarray,
-        bulk_only: bool = False,
+        witness: bool = False,
         pad_rows: bool = False,
     ) -> executor.Schedule:
         """Host-side half of a mine: bucketing, strategy selection, hub
@@ -1433,13 +1438,15 @@ class CompiledPattern:
         included — is canonical per pow2 shape class; callers slice the
         fetched vector back to the real seed count.
 
-        ``bulk_only`` (witness extraction) disables the per-branch hub
-        decomposition — partial top-k payloads from decomposed branches
-        cannot be scatter-merged the way partial counts can, so every
-        seed must stay one row of one launch — and remaps the ``bs2``
-        strategy to ``bs1``: bs2 enumerates the fixed side outermost,
-        which is a different candidate order than bs1/pw (witness
-        selection is order-defined; counting is order-free)."""
+        ``witness`` (witness extraction) remaps the ``bs2`` strategy to
+        ``bs1``: bs2 enumerates the fixed side outermost, which is a
+        different candidate order than bs1/pw (witness selection is
+        order-defined; counting is order-free).  Its hub-tail branch rows
+        scatter into one slot per branch item instead of into their seed
+        (partial top-k payloads cannot be added the way partial counts
+        can); the schedule carries each item's seed and level-1 edge id
+        (``Schedule.item_seed`` / ``item_eid``) for the host-side merge,
+        see :func:`repro.witness.extract.mine_witnesses`."""
         g = self.g
         ir = self.ir
         n = len(seed_eids)
@@ -1453,7 +1460,7 @@ class CompiledPattern:
         strat, cost = self._pass_strategy(
             w_pads, self._pad(d_a_req), self._pad(d_b_req)
         )
-        if bulk_only:
+        if witness:
             strat = np.where(strat == 1, 0, strat).astype(np.int32)
 
         has_inter = ir.intersect is not None
@@ -1461,7 +1468,6 @@ class CompiledPattern:
         branch_ok = (
             k >= 1
             and isinstance(ir.frontiers[0].operand, Neigh)
-            and not bulk_only
         )
         go_branch = (
             (cost > BRANCH_DECOMP_COST)
@@ -1500,8 +1506,9 @@ class CompiledPattern:
 
         # ---- hub tail: per-branch decomposition, re-bucketed per level
         hub = np.nonzero(go_branch)[0]
+        seed_of = item_eid = None
         if len(hub):
-            item_seed_l, fr, frt = self._expand_branches(
+            item_seed_l, fr, frt, item_eid = self._expand_branches(
                 src[hub], dst[hub], st[hub]
             )
             if len(fr):
@@ -1538,6 +1545,8 @@ class CompiledPattern:
                     self._pad(bd_a),
                     self._pad(bd_b),
                 )
+                if witness:
+                    bstrat = np.where(bstrat == 1, 0, bstrat).astype(np.int32)
                 use_a = has_inter & np.isin(bstrat, (0, 2))
                 use_b = (has_inter & np.isin(bstrat, (1, 2))) | (
                     has_ce & (bstrat == 2)
@@ -1563,10 +1572,21 @@ class CompiledPattern:
                     seed_of=seed_of,
                 )
         n_dev = _pow2ceil(max(1, n)) if pad_rows else n
+        if not witness:
+            return executor.Schedule(
+                groups=self._stage_groups(groups, n_dev, pad_rows=pad_rows),
+                branch_items=branch_items,
+                n_out=n_dev,
+            )
+        bulk = [gs for gs in groups if not gs.branch]
+        items = [gs for gs in groups if gs.branch]
         return executor.Schedule(
-            groups=self._stage_groups(groups, n_dev, pad_rows=pad_rows),
+            groups=self._stage_groups(bulk, n)
+            + self._stage_groups(items, branch_items, item_rows=True),
             branch_items=branch_items,
-            n_out=n_dev,
+            n_out=n,
+            item_seed=seed_of if branch_items else None,
+            item_eid=item_eid if branch_items else None,
         )
 
     def _schedule_shape_keyed(
@@ -1612,7 +1632,7 @@ class CompiledPattern:
         self,
         seed_eids: np.ndarray,
         stats: Optional[Dict[str, int]] = None,
-        bulk_only: bool = False,
+        witness: bool = False,
     ) -> executor.Schedule:
         """The cached bucket schedule for a seed set (building it on a
         miss).  Schedules are pure in (plan, graph degree requirements,
@@ -1623,15 +1643,15 @@ class CompiledPattern:
         Under ``schedule_mode="shape"`` (streaming), counting schedules
         are re-keyed on the pow2-padded launch profile instead of the
         seed identity — see :meth:`_schedule_shape_keyed`.  Witness
-        (``bulk_only``) schedules stay value-keyed in both modes: their
+        schedules stay value-keyed in both modes: their
         packed top-k payloads depend on exact seed order."""
         stats = self.stats if stats is None else stats
-        if self.schedule_mode == "shape" and not bulk_only:
+        if self.schedule_mode == "shape" and not witness:
             return self._schedule_shape_keyed(seed_eids, stats)
         key = (
             len(seed_eids),
             hashlib.sha1(seed_eids.tobytes()).hexdigest(),
-            bulk_only,
+            witness,
         )
         with self._sched_lock:
             sched = self._schedules.get(key)
@@ -1647,9 +1667,9 @@ class CompiledPattern:
             "schedule_build",
             pattern=self.spec.name,
             n_seeds=len(seed_eids),
-            bulk_only=bulk_only,
+            witness=witness,
         ):
-            sched = self._build_schedule(seed_eids, bulk_only=bulk_only)
+            sched = self._build_schedule(seed_eids, witness=witness)
         with self._sched_lock:
             existing = self._schedules.get(key)
             if existing is not None:

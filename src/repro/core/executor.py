@@ -223,6 +223,10 @@ class Schedule:
     groups: List[BucketGroup]
     branch_items: int
     n_out: int
+    # witness schedules only: branch rows scatter into one slot per hub
+    # branch item; per item, its seed's index and its level-1 edge id
+    item_seed: Optional[np.ndarray] = None
+    item_eid: Optional[np.ndarray] = None
 
 
 def build_staging(

@@ -6,9 +6,12 @@ laundering typologies — fan-in, fan-out, cycles, scatter-gather, and
 stacked bipartite ("stack") — at LI (low-illicit) / HI (high-illicit)
 rates.  Edge labels mark ground-truth laundering transactions.
 
-The real datasets (6.9M–180M edges) are not shipped in this container; the
-presets keep the six published names at CPU-tractable scales (factor noted
-in EXPERIMENTS.md).  Every generator is deterministic in ``seed``.
+The real datasets (5.08M–180M transactions) are not shipped here.  Each
+preset keeps a published name at a small base size, and ``scale``
+multiplies its accounts and transfers together: HI-Small at scale 282 has
+451,200 accounts and ~5.14M transactions, against the published 515,088
+accounts and 5,078,345 transactions (``chip_smoke.py`` runs that size).
+Every generator is deterministic in ``seed``.
 
 **Plant-and-recover**: every injected typology instance is tracked
 through the final edge-id shuffle — ``meta["instances"]`` lists, per

@@ -19,10 +19,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # newer jax exposes it top-level
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_forward", "pipeline_spec"]

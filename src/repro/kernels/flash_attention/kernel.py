@@ -36,8 +36,8 @@ def _kernel(bq: int, bk: int, causal: bool, scale: float, q_ref, k_ref, v_ref, o
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.ds(j * bk, bk), slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(j * bk, bk), slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T  # (bq, bk)
         if causal:
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)

@@ -1,34 +1,30 @@
 """Pallas TPU kernel: padded-tile weighted temporal intersection count.
 
 TPU adaptation of BlazingAML's warp-cooperative sorted-set intersection:
-instead of per-lane binary search (GPU) we stage both padded neighbor
-tiles in VMEM and do a branch-free broadcast-compare over the (Da, Db)
-pair grid — pure VPU work on 8x128 vector registers, no gathers, no
-data-dependent control flow.  This mirrors the compiler's ``pw`` strategy
-(`repro.core.compiler`), which is what low-degree buckets (the bulk of a
-power-law transaction graph) lower to.
+instead of per-lane binary search (GPU) both padded neighbor tiles are
+staged in VMEM and compared branch-free over the pair grid — pure VPU
+work, no gathers, no data-dependent control flow.  This mirrors the
+compiler's ``pw`` strategy (`repro.core.compiler`), which is what
+low-degree buckets (the bulk of a power-law transaction graph) lower to.
 
-Inputs (per row r of a batch B):
-  a_ids (B, Da) int32   frontier-side neighbor ids   (-1 = padding)
-  a_t   (B, Da) int32   frontier-side edge times
-  b_ids (B, Db) int32   fixed-side neighbor ids      (-1 = padding)
-  b_t   (B, Db) int32   fixed-side edge times
-  a_lo, a_hi (B,) int32 frontier-side window  (a_lo < t <= a_hi)
-  b_lo, b_hi (B,) int32 fixed-side window     (b_lo < t <= b_hi)
+Layout is lane-dense: kernel rows (one per query) run along the 128-wide
+lane axis and the neighbor slots along sublanes, so every vector register
+is full even when a bucket's widths are 4.  Inputs (``ops.py`` folds the
+window predicates into the ids and transposes):
+
+  x_ids (Dx, R) int32   tile side: one column per row   (Dx % 8 == 0)
+  y_ids (Dy, R) int32   loop side                        (Dy % 8 == 0)
+  x_t, y_t             edge times, passed only when ``order != 0``
 Output:
-  counts (B,) int32 — # pairs (i, j): a_ids[r,i] == b_ids[r,j] >= 0,
-  both windows hold, and (if ordered) b_t[r,j] > a_t[r,i].
+  counts (1, R) int32 — # pairs (i, j) with x_ids[i, r] == y_ids[j, r]
+  (and y_t[j, r] > x_t[i, r] for ``order=+1``, < for ``order=-1``).
 
-Block tiling: grid over B; each step loads (bm, Da) + (bm, Db) tiles into
-VMEM and materializes a (bm, Da, Db) compare cube.  ``ops.py`` picks bm so
-the cube stays within the VMEM budget (bm * Da * Db <= ~2^21 int32 lanes ~= 8MB).
-
-The compiled mining executor (``repro.core.compiler`` with
-``backend="pallas"``) lowers every ``pw``-strategy bucket onto this op:
-the (B, W1..Wk) query shape is flattened to kernel rows, Da/Db are the
-bucket-ladder expansion widths, and hub-tail sweeps run the op inside a
-``fori_loop`` over row offsets — so the same kernel serves every bucket
-of the power-law degree ladder with a statically VMEM-safe tile.
+Invalid slots carry distinct negative sentinels per side, so equality
+alone decides a match.  Each grid step holds a ``(Dx, bn)`` tile and
+walks the loop side eight sublanes at a time: a row of ``y`` broadcasts
+down the sublanes of the ``x`` tile, and the hit tile folds into an
+``(8, bn)`` accumulator with vreg adds (no cross-lane reduction until
+the single final one).  No (bm, Da, Db) cube is ever materialized.
 """
 from __future__ import annotations
 
@@ -40,60 +36,68 @@ from jax.experimental import pallas as pl
 
 __all__ = ["intersect_count_pallas"]
 
+_SUB = 8  # sublanes per vreg
 
-def _kernel(ordered: bool, a_ids, a_t, b_ids, b_t, a_lo, a_hi, b_lo, b_hi, out):
-    ai = a_ids[...]  # (bm, Da)
-    at = a_t[...]
-    bi = b_ids[...]  # (bm, Db)
-    bt = b_t[...]
-    alo = a_lo[...][:, None]
-    ahi = a_hi[...][:, None]
-    blo = b_lo[...][:, None]
-    bhi = b_hi[...][:, None]
 
-    a_ok = (ai >= 0) & (at > alo) & (at <= ahi)  # (bm, Da)
-    b_ok = (bi >= 0) & (bt > blo) & (bt <= bhi)  # (bm, Db)
-    eq = ai[:, :, None] == bi[:, None, :]  # (bm, Da, Db)
-    pair = eq & a_ok[:, :, None] & b_ok[:, None, :]
-    if ordered:
-        pair = pair & (bt[:, None, :] > at[:, :, None])
-    out[...] = jnp.sum(pair.astype(jnp.int32), axis=(1, 2))
+def _kernel(order: int, *refs):
+    if order:
+        x_ids, x_t, y_ids, y_t, out = refs
+        xt = x_t[...]
+    else:
+        x_ids, y_ids, out = refs
+    xi = x_ids[...]  # (Dx, bn)
+    dx, bn = xi.shape
+
+    def body(g, acc):
+        r0 = pl.multiple_of(g * _SUB, _SUB)
+        yi8 = y_ids[pl.ds(r0, _SUB), :]  # (8, bn)
+        if order:
+            yt8 = y_t[pl.ds(r0, _SUB), :]
+        for r in range(_SUB):
+            hit = xi == yi8[r : r + 1, :]
+            if order > 0:
+                hit = hit & (yt8[r : r + 1, :] > xt)
+            elif order < 0:
+                hit = hit & (yt8[r : r + 1, :] < xt)
+            acc = acc + jnp.sum(
+                hit.astype(jnp.int32).reshape(dx // _SUB, _SUB, bn), axis=0
+            )
+        return acc
+
+    n_groups = y_ids.shape[0] // _SUB
+    acc = jax.lax.fori_loop(
+        0, n_groups, body, jnp.zeros((_SUB, bn), jnp.int32)
+    )
+    out[...] = jnp.sum(acc, axis=0, keepdims=True)
 
 
 def intersect_count_pallas(
-    a_ids,
-    a_t,
-    b_ids,
-    b_t,
-    a_lo,
-    a_hi,
-    b_lo,
-    b_hi,
+    x_ids,
+    y_ids,
+    x_t=None,
+    y_t=None,
     *,
-    ordered: bool = False,
-    block_rows: int = 8,
+    order: int = 0,
+    block_cols: int = 128,
     interpret: bool = True,
 ):
-    b, da = a_ids.shape
-    _, db = b_ids.shape
-    assert b % block_rows == 0, "pad batch to a multiple of block_rows"
-    grid = (b // block_rows,)
-    row_spec2 = lambda w: pl.BlockSpec((block_rows, w), lambda i: (i, 0))
-    row_spec1 = pl.BlockSpec((block_rows,), lambda i: (i,))
+    dx, r = x_ids.shape
+    dy = y_ids.shape[0]
+    assert dx % _SUB == 0 and dy % _SUB == 0, "pad slot axes to 8"
+    assert r % block_cols == 0, "pad rows to a multiple of block_cols"
+    spec = lambda d: pl.BlockSpec((d, block_cols), lambda i: (0, i))
+    if order:
+        args = (x_ids, x_t, y_ids, y_t)
+        in_specs = [spec(dx), spec(dx), spec(dy), spec(dy)]
+    else:
+        args = (x_ids, y_ids)
+        in_specs = [spec(dx), spec(dy)]
     return pl.pallas_call(
-        functools.partial(_kernel, ordered),
-        grid=grid,
-        in_specs=[
-            row_spec2(da),
-            row_spec2(da),
-            row_spec2(db),
-            row_spec2(db),
-            row_spec1,
-            row_spec1,
-            row_spec1,
-            row_spec1,
-        ],
-        out_specs=row_spec1,
-        out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
+        functools.partial(_kernel, order),
+        grid=(r // block_cols,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_cols), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, r), jnp.int32),
         interpret=interpret,
-    )(a_ids, a_t, b_ids, b_t, a_lo, a_hi, b_lo, b_hi)
+        name="intersect_count",
+    )(*args)

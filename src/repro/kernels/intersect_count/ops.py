@@ -1,41 +1,56 @@
 """Jitted wrapper for the intersect_count Pallas kernel.
 
-Pads the batch to a block multiple and picks ``block_rows`` so the
-(bm, Da, Db) compare cube stays inside the VMEM budget.  On non-TPU
-backends the kernel runs in interpret mode (correctness path); on TPU it
-compiles to a Mosaic kernel.
+Takes the row-major query layout the compiler builds — ``(B, Da)`` /
+``(B, Db)`` neighbor tiles plus per-row windows — folds each side's
+window predicate into its ids (a slot outside its window becomes a
+side-specific negative sentinel, so the kernel tests equality only),
+puts the wider side on the kernel's tile and the narrower one on its
+loop, pads both slot axes to 8 and the rows to the lane block, and
+transposes to the kernel's lane-dense ``(D, rows)`` layout.  On TPU the
+kernel compiles to a Mosaic custom call; on other backends it runs in
+interpret mode (the correctness path).
 
-This wrapper is shape-polymorphic only in Python: called under ``jit``
-(the compiled mining path routes every ``pw``-strategy bucket through it
-when ``backend="pallas"``), the batch and tile dims are static bucket
-ladder widths, so :func:`block_rows_for` resolves the VMEM tiling at
-trace time and the pad/unpad slices fuse into the surrounding program.
+Called under ``jit`` (the compiled mining path routes every
+``pw``-strategy bucket through it when ``backend="pallas"``) the batch
+and tile dims are static bucket-ladder widths, so :func:`_block_cols`
+resolves the tiling at trace time and the fold/pad/transpose fuse into
+the surrounding program.  Every trace bumps
+``repro_kernel_traces_total{kernel="intersect_count",mode=...}`` so a
+caller can check which lowering its mine took.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels.intersect_count.kernel import intersect_count_pallas
+from repro.obs import metrics as obs_metrics
 
-__all__ = ["intersect_count", "block_rows_for"]
+__all__ = ["intersect_count"]
 
-_VMEM_INT32_BUDGET = 1 << 21  # ~8 MB of int32 lanes for the compare cube
-
-
-def block_rows_for(da: int, db: int) -> int:
-    """Rows per grid step so the (bm, da, db) compare cube fits the VMEM
-    budget; power-of-two, capped at 256 rows.  ``da``/``db`` are bucket
-    ladder widths on the compiled path, so the tile shape is a pure
-    function of the bucket."""
-    bm = max(1, _VMEM_INT32_BUDGET // max(1, da * db))
-    return 1 << min(8, max(0, int(bm).bit_length() - 1))
+_LANES = 128
+_SUB = 8
+_TILE_ELEMS = 1 << 16  # (Dx, bn) elements compared per loop step
+_MAX_COLS = 2048
+# sentinels of invalid slots, distinct so no two ever match: out-of-window
+# slots of side a / side b, then slot padding of the tile / loop side
+_A_OFF, _B_OFF, _X_PAD, _Y_PAD = -1, -2, -3, -4
 
 
-_block_rows = block_rows_for  # backwards-compatible private alias
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _block_cols(dx: int, rows: int) -> int:
+    """Rows (lanes) per grid step: about ``_TILE_ELEMS`` compares per
+    step over a ``dx``-slot tile, a power-of-two multiple of 128, no
+    wider than the padded row count needs."""
+    bn = _LANES
+    while bn < _MAX_COLS and 2 * bn * dx <= _TILE_ELEMS:
+        bn *= 2
+    while bn > _LANES and bn // 2 >= rows:
+        bn //= 2
+    return bn
 
 
 def intersect_count(
@@ -53,29 +68,44 @@ def intersect_count(
 ):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    obs_metrics.get_registry().counter(
+        "repro_kernel_traces_total",
+        help="Pallas kernel traces, by lowering (mosaic | interpret)",
+        labels={
+            "kernel": "intersect_count",
+            "mode": "interpret" if interpret else "mosaic",
+        },
+    ).inc()
     b, da = a_ids.shape
     db = b_ids.shape[1]
-    bm = _block_rows(da, db)
-    pad = (-b) % bm
-    if pad:
-        z2 = lambda a, w: jnp.concatenate(
-            [a, jnp.full((pad, w), -1, dtype=a.dtype)], axis=0
+    a_ok = (a_ids >= 0) & (a_t > a_lo[:, None]) & (a_t <= a_hi[:, None])
+    b_ok = (b_ids >= 0) & (b_t > b_lo[:, None]) & (b_t <= b_hi[:, None])
+    a_ids = jnp.where(a_ok, a_ids, _A_OFF)
+    b_ids = jnp.where(b_ok, b_ids, _B_OFF)
+    # the wider side is the tile, the narrower one the loop; ordered
+    # means b_t > a_t, i.e. loop_t > tile_t unless the sides swapped
+    if da >= db:
+        x_ids, x_t, y_ids, y_t, order = a_ids, a_t, b_ids, b_t, 1
+    else:
+        x_ids, x_t, y_ids, y_t, order = b_ids, b_t, a_ids, a_t, -1
+    dx, dy = x_ids.shape[1], y_ids.shape[1]
+    dxp, dyp = _round_up(dx, _SUB), _round_up(dy, _SUB)
+    bn = _block_cols(dxp, _round_up(b, _LANES))
+    rows = _round_up(b, bn)
+
+    def lane_dense(a, d, pad):
+        a = jnp.pad(
+            a, ((0, rows - b), (0, d - a.shape[1])), constant_values=pad
         )
-        z1 = lambda a: jnp.concatenate([a, jnp.zeros((pad,), dtype=a.dtype)])
-        a_ids, a_t = z2(a_ids, da), z2(a_t, da)
-        b_ids, b_t = z2(b_ids, db), z2(b_t, db)
-        a_lo, a_hi, b_lo, b_hi = map(z1, (a_lo, a_hi, b_lo, b_hi))
+        return a.T
+
+    args = [lane_dense(x_ids, dxp, _X_PAD), lane_dense(y_ids, dyp, _Y_PAD)]
+    if ordered:
+        args += [lane_dense(x_t, dxp, 0), lane_dense(y_t, dyp, 0)]
     out = intersect_count_pallas(
-        a_ids,
-        a_t,
-        b_ids,
-        b_t,
-        a_lo,
-        a_hi,
-        b_lo,
-        b_hi,
-        ordered=ordered,
-        block_rows=bm,
+        *args,
+        order=order if ordered else 0,
+        block_cols=bn,
         interpret=interpret,
     )
-    return out[:b]
+    return out[0, :b]

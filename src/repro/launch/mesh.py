@@ -17,6 +17,7 @@ import os
 
 import numpy as np
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
@@ -27,30 +28,46 @@ __all__ = [
 
 
 def ensure_host_devices(n: int) -> int:
-    """Request ``n`` virtual host (CPU) devices and return the count
-    actually visible.
+    """Make ``n`` devices visible and return the count actually visible.
 
-    Must run before jax's backend initializes (the flag is read once, at
-    CPU client creation) — callers that get fewer devices back than they
-    asked for are running after init (or on a real multi-chip platform)
-    and should degrade to the visible device set rather than fail."""
-    flag = f"--xla_force_host_platform_device_count={int(n)}"
+    On the CPU platform the devices are virtual: the
+    ``--xla_force_host_platform_device_count`` flag is appended to
+    ``XLA_FLAGS``, which takes effect only before jax's backend
+    initializes (the flag is read once, at CPU client creation) — a CPU
+    caller that gets fewer back is running after init and may degrade
+    to the visible set.  The flag is skipped when ``JAX_PLATFORMS``
+    names another platform first; with ``JAX_PLATFORMS`` unset it is
+    added, and is inert on an accelerator (it sizes the host platform
+    only, and the platform JAX picks is not known before its backend
+    initializes).  On an accelerator the chips are what they are: fewer
+    than ``n`` raises instead of time-sharing partitions on fewer
+    chips."""
+    platforms = (jax.config.jax_platforms or "cpu").split(",")
     cur = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in cur:
+    if platforms[0] == "cpu" and "--xla_force_host_platform_device_count" not in cur:
+        flag = f"--xla_force_host_platform_device_count={int(n)}"
         os.environ["XLA_FLAGS"] = f"{cur} {flag}".strip()
-    return len(jax.devices())
+    devs = jax.devices()
+    if devs[0].platform != "cpu" and len(devs) < n:
+        raise RuntimeError(
+            f"{n} devices requested, {len(devs)} {devs[0].platform} "
+            "device(s) present"
+        )
+    return len(devs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def make_shard_mesh(devices):

@@ -9,11 +9,12 @@ partitioner has balanced expected cost (``graph/partition.py``).  The
 default ``--backend sharded`` path runs the real multi-device executor
 (:mod:`repro.core.shard`): every partition's chunk launches land on its
 own device with a per-device resident accumulator and exactly one
-blocking host sync per mine.  On this 1-CPU container the launcher
+blocking host sync per mine.  On the CPU platform the launcher
 requests ``--devices`` virtual devices in-process via
 ``repro.launch.mesh.ensure_host_devices`` (the
 ``--xla_force_host_platform_device_count`` flag) before first jax
-backend init.  ``--backend partitioned`` keeps the sequential
+backend init; on an accelerator, asking for more devices than the host
+has is an error.  ``--backend partitioned`` keeps the sequential
 single-device loop for comparison.
 
 Mining goes through a portfolio :class:`repro.api.MiningSession`, so
@@ -103,9 +104,13 @@ def main():
         from repro.launch.mesh import ensure_host_devices
 
         want = args.devices or args.parts
-        got = ensure_host_devices(want)
+        got = ensure_host_devices(want)  # raises on a short accelerator
         if got < want:
-            print(f"# requested {want} devices, got {got} (degrading)")
+            print(f"# requested {want} CPU devices, got {got} (degrading)")
+
+    from repro.launch.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.core.patterns import PATTERN_NAMES
     from repro.data.synth_aml import load_dataset

@@ -334,7 +334,10 @@ def load_test(server: TriageServer, feed: Feed, n_submitters: int) -> dict:
     return out
 
 
-def main():
+def main(argv=None):
+    """CLI load test.  Exits non-zero when any submit failed: the
+    server's per-request error reply keeps it serving, but a run with
+    failed ticks is not a result."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dataset", default="HI-Small")
     ap.add_argument("--scale", type=float, default=0.25)
@@ -345,10 +348,12 @@ def main():
     ap.add_argument("--witnesses", type=int, default=2)
     ap.add_argument("--max-batches", type=int, default=0, help="0 = whole feed")
     ap.add_argument("--audit", default=None, help="JSONL alert audit log path")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.data.synth_aml import generate_aml_dataset
+    from repro.launch.jax_cache import enable_compile_cache
 
+    enable_compile_cache()
     ds = generate_aml_dataset(
         args.dataset, seed=args.seed, scale=args.scale, window=args.window
     )
@@ -368,8 +373,13 @@ def main():
         f"{args.submitters} submitters, witnesses={args.witnesses}"
     )
     out = load_test(server, feed, args.submitters)
+    last_error = server.last_error
     server.close()
     print(json.dumps(out, indent=2))
+    if out["errors"]:
+        raise SystemExit(
+            f"{out['errors']} of {len(feed)} submits failed; last: {last_error}"
+        )
 
 
 if __name__ == "__main__":

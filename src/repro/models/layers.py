@@ -264,10 +264,7 @@ def moe_apply_shard_map(p, x, cfg: ModelConfig):
     lowering (P7) was refuted — the partitioner all-gathered the token
     buffer; shard_map makes the locality explicit.
     """
-    try:
-        from jax import shard_map  # newer jax exposes it top-level
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, data_axes, model_axes = ctx.mesh_and_axes()

@@ -26,20 +26,25 @@ sweep offset) as sort keys, and a ``lax.fori_loop`` merges combos with a
 multi-operand ``jax.lax.sort`` — so the selection order is independent
 of the sweep decomposition, and a swept bucket is still ONE launch.
 
-Witness schedules are **bulk-only** (``schedule_for(..., bulk_only=True)``):
-the per-branch hub decomposition scatter-adds partial counts from many
-rows into one seed, which cannot merge packed top-k payloads; bulk-only
-schedules keep every seed in exactly one row of one launch, so the
-``.at[seg].set`` scatter of the packed ids is race-free.  For the same
-reason the ``bs2`` strategy is remapped to ``bs1`` (bs2 enumerates the
-fixed side outermost — a different candidate order), and the pairwise
-compare cube always takes the XLA broadcast path (the Pallas
-``intersect_count`` op returns reduced counts, not positions).
+Witness schedules (``schedule_for(..., witness=True)``) keep the
+counting path's per-branch hub decomposition: a seed whose level-1
+frontier is too costly to pad as one row is split host-side into one row
+per level-1 branch item, so a hub on that frontier costs its own degree
+rather than the seed's whole padded cube.  Bulk rows scatter their packed
+ids straight into their seed's slot (one row per seed, so ``.at[seg].set``
+is race-free); branch rows scatter into one slot per ITEM, and the host
+merge concatenates a seed's items in item order — the level-1 axis's
+canonical order — and keeps the first k.  The ``bs2`` strategy is
+remapped to ``bs1`` (bs2 enumerates the fixed side outermost — a
+different candidate order), and the pairwise compare cube always takes
+the XLA broadcast path (the Pallas ``intersect_count`` op returns reduced
+counts, not positions).
 
-Execution mirrors :func:`repro.core.executor.execute` with TWO device
-accumulators — per-seed counts (scatter-add) and packed ``(B, k, H)``
-witness ids (scatter-set) — and the mine's single host sync fetches both
-in one ``jax.device_get``.
+Execution mirrors :func:`repro.core.executor.execute` with device
+accumulators for per-seed counts (scatter-add) and packed ``(B, k, H)``
+witness ids (scatter-set), plus the per-item pair of them for branch
+rows; the mine's single host sync fetches all of them in one
+``jax.device_get``.
 """
 from __future__ import annotations
 
@@ -59,10 +64,19 @@ __all__ = ["mine_witnesses"]
 
 
 def _build_witness_kernel(
-    ir, n_iters: int, strat: int, dims: Tuple[int, ...], sweeps: Tuple[int, ...], kp: int
+    ir,
+    n_iters: int,
+    strat: int,
+    dims: Tuple[int, ...],
+    sweeps: Tuple[int, ...],
+    kp: int,
+    branch: bool,
 ) -> Callable:
     """Lower the stage graph to one jitted top-k witness kernel for a
-    fixed (strategy, bucket widths, sweep grid, k-capacity) combination.
+    fixed (strategy, bucket widths, sweep grid, k-capacity, branch mode)
+    combination.  In branch mode each row is one hub branch item whose
+    level-1 node and time arrive as ``fr``/``frt``; its level-1 hop comes
+    back as ``-1`` and the host merge fills in the item's edge id.
 
     Returns ``kernel(dg, s, d, st_, fr, frt) -> (counts (B,), eids
     (B, kp, H))`` — counts are the exact per-row instance counts (same
@@ -107,7 +121,7 @@ def _build_witness_kernel(
             return dg.out_eid if sorted_by == "id" else dg.out_eid_t
         return dg.in_eid if sorted_by == "id" else dg.in_eid_t
 
-    def body(dg: DeviceGraph, s, d, st_, offs):
+    def body(dg: DeviceGraph, s, d, st_, fr, frt, offs):
         B = s.shape[0]
         node_env = {"seed.src": (s, 0), "seed.dst": (d, 0)}
         time_env: Dict[str, Tuple] = {}
@@ -129,7 +143,16 @@ def _build_witness_kernel(
         # ---- frontier chain (counting-kernel order, positions kept) ---
         # frontier_hops[lvl-1] = (pos cube, eid rows) or None for unions
         frontier_hops: List[Optional[Tuple]] = []
-        for lvl in range(1, k + 1):
+        start_level = 1
+        if branch:
+            f1 = ir.frontiers[0]
+            bmask = (fr >= 0)[:, None]
+            node_env[f1.name] = (jnp.where(bmask, fr[:, None], -1), 1)
+            time_env[f1.name] = (frt[:, None], 1)
+            mask_env[f1.name] = (bmask, 1)
+            frontier_hops.append(None)  # the item's edge, filled host-side
+            start_level = 2
+        for lvl in range(start_level, k + 1):
             fa = ir.frontiers[lvl - 1]
             width = dims[lvl - 1]
             off = offs[lvl - 1]
@@ -392,10 +415,9 @@ def _build_witness_kernel(
     nk = n_axes + 1
 
     def kernel(dg: DeviceGraph, s, d, st_, fr, frt):
-        del fr, frt  # witness schedules are bulk-only
         if n_sweep == 1:
             offs = tuple(jnp.int32(0) for _ in dims)
-            total, _, planes = body(dg, s, d, st_, offs)
+            total, _, planes = body(dg, s, d, st_, fr, frt, offs)
             return total, jnp.stack(planes, axis=-1)
 
         def step(i, carry):
@@ -404,7 +426,7 @@ def _build_witness_kernel(
                 ((i // strides[j]) % sweeps[j]) * jnp.int32(dims[j])
                 for j in range(len(dims))
             )
-            t2, keys, planes = body(dg, s, d, st_, offs)
+            t2, keys, planes = body(dg, s, d, st_, fr, frt, offs)
             kc = jnp.concatenate([kacc, jnp.stack(keys, axis=-1)], axis=1)
             pc = jnp.concatenate([pacc, jnp.stack(planes, axis=-1)], axis=1)
             operands = tuple(kc[:, :, j] for j in range(nk)) + tuple(
@@ -427,21 +449,41 @@ def _build_witness_kernel(
     return kernel
 
 
-def _witness_kernel(cp, strat: int, dims, sweeps, kp: int) -> Callable:
+def _witness_kernel(cp, strat: int, dims, sweeps, kp: int, branch: bool) -> Callable:
     """The plan's cached jitted witness kernel for one trace shape (the
     "wit" tag keeps the key disjoint from the counting-kernel keys in the
     shared, possibly cross-tick, kernels cache)."""
-    key = (cp.n_iters, "wit", strat, dims, sweeps, kp)
+    key = (cp.n_iters, "wit", strat, dims, sweeps, kp, branch)
     fn = cp._kernels.get(key)  # lock-free warm path
     if fn is None:
         with cp._jit_lock:
             fn = cp._kernels.get(key)
             if fn is None:
                 fn = jax.jit(
-                    _build_witness_kernel(cp.ir, cp.n_iters, strat, dims, sweeps, kp)
+                    _build_witness_kernel(
+                        cp.ir, cp.n_iters, strat, dims, sweeps, kp, branch
+                    )
                 )
                 cp._kernels[key] = fn
     return fn
+
+
+def _merge_items(counts, eids, item_cnt, item_eids, item_seed, item_eid) -> None:
+    """Fold hub branch items into their seeds, in place.  Items are
+    grouped by seed and in level-1 canonical order within a seed, so a
+    seed's witnesses are its items' witnesses in item order; the first
+    ``kp`` of them are kept, each with its item's level-1 edge id."""
+    kp = eids.shape[1]
+    np.add.at(counts, item_seed, item_cnt)
+    found = np.minimum(item_cnt, kp)
+    item = np.repeat(np.arange(len(found)), found)
+    j = np.arange(len(item)) - np.repeat(np.cumsum(found) - found, found)
+    seed = item_seed[item]
+    rank = np.arange(len(item)) - np.searchsorted(seed, seed, side="left")
+    keep = rank < kp
+    tup = item_eids[item[keep], j[keep]]
+    tup[:, 0] = item_eid[item[keep]]
+    eids[seed[keep], rank[keep]] = tup
 
 
 def mine_witnesses(
@@ -455,12 +497,14 @@ def mine_witnesses(
     """Mine per-seed counts AND top-k witness hop tuples for a compiled
     plan, device-resident end to end.
 
-    Mirrors ``CompiledPattern.mine`` — bulk-only bucket schedule, one
+    Mirrors ``CompiledPattern.mine`` — witness bucket schedule, one
     ``device_put`` per group, async launches accumulated on device — with
-    two accumulators (counts scatter-add, packed eids scatter-set; rows
-    are unique per seed in bulk mode, so set is race-free) and exactly
-    ONE blocking device→host sync fetching both together.  ``k`` is
-    pow2-ceiled for the trace key and trimmed host-side.
+    per-seed accumulators for bulk rows (counts scatter-add, packed eids
+    scatter-set; one row per seed, so set is race-free), per-item ones
+    for hub branch rows, and exactly ONE blocking device→host sync
+    fetching all of them together; branch items are merged into their
+    seeds host-side (:func:`_merge_items`).  ``k`` is pow2-ceiled for the
+    trace key and trimmed host-side.
     """
     if k < 1:
         raise ValueError("witnesses=k must be >= 1")
@@ -481,25 +525,32 @@ def mine_witnesses(
             eids=np.full((0, int(k), H), -1, dtype=np.int64),
         )
     stats = cp.stats
-    sched = cp.schedule_for(seed_eids, stats, bulk_only=True)
+    sched = cp.schedule_for(seed_eids, stats, witness=True)
+    n_items = 0 if sched.item_seed is None else len(sched.item_seed)
     dgraph = cp.dg if dg is None else dg
     with jax.default_device(device):  # allocate accumulators in place
-        out_cnt = jnp.zeros(n, jnp.int32)
-        out_eids = jnp.full((n, kp, H), -1, jnp.int32)
+        acc = {
+            False: [jnp.zeros(n, jnp.int32), jnp.full((n, kp, H), -1, jnp.int32)],
+            True: [
+                jnp.zeros(n_items, jnp.int32),
+                jnp.full((n_items, kp, H), -1, jnp.int32),
+            ],
+        }
     local_keys: set = set()
     for grp in sched.groups:
         dev = jax.device_put(grp.staging, device)
         stats["bytes_h2d"] += sum(int(a.nbytes) for a in grp.staging)
-        fn = _witness_kernel(cp, grp.strat, grp.dims, grp.sweeps, kp)
+        fn = _witness_kernel(cp, grp.strat, grp.dims, grp.sweeps, kp, grp.branch)
+        out = acc[grp.branch]
         s0 = 0
         for w in grp.widths:
             sl = slice(s0, s0 + w)
             ss, dd, tt, ff, fft, seg = (a[sl] for a in dev)
             cnt, eids = fn(dgraph, ss, dd, tt, ff, fft)
-            out_cnt = out_cnt.at[seg].add(cnt, mode="drop")
-            out_eids = out_eids.at[seg].set(eids, mode="drop")
+            out[0] = out[0].at[seg].add(cnt, mode="drop")
+            out[1] = out[1].at[seg].set(eids, mode="drop")
             local_keys.add(
-                (cp.n_iters, "wit", grp.strat, grp.dims, grp.sweeps, kp, w)
+                (cp.n_iters, "wit", grp.strat, grp.dims, grp.sweeps, kp, grp.branch, w)
             )
             stats["kernel_calls"] += 1
             stats["padded_elements"] += w * grp.per_row * grp.n_sweep
@@ -508,11 +559,16 @@ def mine_witnesses(
         new_keys = local_keys - cp._trace_keys
         cp._trace_keys |= new_keys
     stats["jit_cache_entries"] += len(new_keys)
+    stats["branch_items"] += n_items
     # THE host sync: counts and packed witness ids in one transfer
-    cnt_h, eids_h = jax.device_get((out_cnt, out_eids))
+    fetched = jax.device_get((acc[False], acc[True]))
     stats["host_syncs"] += 1
-    stats["bytes_d2h"] += int(cnt_h.nbytes) + int(eids_h.nbytes)
+    stats["bytes_d2h"] += sum(int(a.nbytes) for pair in fetched for a in pair)
+    (cnt_h, eids_h), (icnt_h, ieids_h) = fetched
     counts = cnt_h.astype(np.int64)
+    eids_h = np.array(eids_h)
+    if n_items:
+        _merge_items(counts, eids_h, icnt_h, ieids_h, sched.item_seed, sched.item_eid)
     return Witnesses(
         pattern=cp.spec.name,
         hops=layout,

@@ -23,7 +23,7 @@ import json
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import smoke_config
 from repro.distributed.optimizer import AdamWConfig, adamw_init, adamw_update
@@ -55,7 +55,7 @@ p_ref, o_ref, loss_ref = jax.jit(train_step)(params, opt, batch)
 out["loss_ref"] = float(loss_ref)
 
 # sharded: 2-way data x 4-way model
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 p_specs = param_specs(cfg)
 p_sh = param_sharding(mesh, p_specs)
 b_sh = batch_sharding(mesh, jax.eval_shape(lambda: batch))
@@ -82,7 +82,7 @@ out["max_param_diff"] = max(jax.tree_util.tree_leaves(diffs))
 # elastic checkpoint: save from the (2,4) mesh, restore onto (4,2)
 ck = os.environ["CK_DIR"]
 save_checkpoint(ck, 1, p_shd)
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 p_sh2 = param_sharding(mesh2, p_specs)
 restored, step, _ = restore_checkpoint(ck, params, shardings=p_sh2)
 rd = jax.tree_util.tree_map(

@@ -14,6 +14,7 @@ import dataclasses
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs.registry import smoke_config
 from repro.distributed import ctx
@@ -29,7 +30,7 @@ x = jnp.asarray(rng.normal(size=(32, cfg.d_model)).astype(np.float32))
 
 y_ref, aux_ref = moe_apply(p, x, cfg)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 ctx.set_axes(mesh, ("data",), ("model",))
 y_ep, aux_ep = jax.jit(lambda p, x: moe_apply_shard_map(p, x, cfg))(p, x)
 

@@ -98,7 +98,7 @@ def test_witnesses_k_exceeds_matches():
 @pytest.mark.parametrize("name", ["cycle4", "cycle5", "reciprocal"])
 def test_witness_strategies_match_oracle(name, strategy):
     """Every forced intersect strategy (bs2 is remapped to bs1 in the
-    bulk-only witness schedule) selects the same canonical witnesses."""
+    witness schedule) selects the same canonical witnesses."""
     rng = np.random.default_rng(11)
     g = random_temporal_graph(rng, n_nodes=18, n_edges=140, t_max=256)
     _assert_parity(build_pattern(name, W), g, None, 3, force_strategy=strategy)
@@ -114,6 +114,39 @@ def test_witness_sweeps_and_chunking(name, mode):
     g = random_temporal_graph(rng, n_nodes=18, n_edges=140, t_max=256)
     kw = {"ladder": (2, 4)} if mode == "sweeps" else {"batch_elem_cap": 1 << 8}
     _assert_parity(build_pattern(name, W), g, None, 3, **kw)
+
+
+@pytest.mark.parametrize(
+    "name,mixed",
+    [
+        (n, m)
+        for n in ("cycle4", "cycle5", "peel_chain", "fan_in_chain", "scatter_gather")
+        for m in (False, True)
+        if not (m and n == "peel_chain")  # one cost class: no mix exists
+    ],
+)
+def test_witness_hub_branches_match_oracle(name, mixed, monkeypatch):
+    """Hub seeds split into level-1 branch items — every seed, or (mixed)
+    every seed above the cheapest cost class, so bulk and branch rows
+    meet in one mine: the host merge of per-item top-k, duplicate seeds
+    included, gives the oracle's first k."""
+    import repro.core.compiler as C
+
+    rng = np.random.default_rng(11)
+    g = random_temporal_graph(rng, n_nodes=18, n_edges=140, t_max=256)
+    seeds = np.concatenate([np.arange(g.n_edges), [3, 3, 7]]).astype(np.int32)
+    spec = build_pattern(name, W)
+    cost = -1.0
+    if mixed:
+        probe = CompiledPattern(spec, g)
+        w_pads = [probe._pad(r) for r in probe._frontier_reqs(seeds)]
+        d_a, d_b = probe._intersect_reqs(seeds)
+        cost = float(probe._pass_strategy(w_pads, probe._pad(d_a), probe._pad(d_b))[1].min())
+    monkeypatch.setattr(C, "BRANCH_DECOMP_COST", cost)
+    cp, _ = _assert_parity(spec, g, seeds, 3)
+    assert cp.stats["branch_items"] > 0 and cp.stats["host_syncs"] == 1
+    kinds = {grp.branch for grp in cp.schedule_for(seeds, witness=True).groups}
+    assert kinds == ({True, False} if mixed else {True})
 
 
 def test_witness_k_validation():
