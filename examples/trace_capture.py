@@ -36,8 +36,9 @@ ds = generate_aml_dataset("HI-Small", seed=0, scale=args.scale)
 tracer = obs_trace.get_tracer()
 
 # 1. one sharded mine across all 8 virtual devices ---------------------------
-# spans: schedule_build -> stage/launch per shard under dispatch:shard{k},
-# compile on first-call jit misses, then the single blocking gather
+# spans: mine -> schedule -> schedule/stage/dispatch per shard under
+# dispatch:shard{k}, compile on first-call jit misses, then the single
+# blocking fetch (its wait child) and the assembling fetch
 session = MiningSession(ds.graph, window=W)
 session.register("scatter_gather", "fan_in", "fan_out", "cycle3")
 session.mine()  # warm untraced so the traced mine shows steady state

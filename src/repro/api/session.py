@@ -266,6 +266,7 @@ class _FusedSeedPlan:
                     )
             return jnp.stack(cols, axis=1)  # (B, U)
 
+        kernel.__name__ = kernel.__qualname__ = "fused_seed_local"
         return jax.jit(kernel)
 
     # -- execution ------------------------------------------------------
@@ -295,33 +296,32 @@ class _FusedSeedPlan:
         if unit_sel is None:
             unit_sel = tuple(range(self.n_units))
         n_units = len(unit_sel)
-        fn = self._jitted.get(unit_sel)  # lock-free warm path
-        if fn is None:
-            with self._jit_lock:
-                fn = self._jitted.get(unit_sel)
-                if fn is None:
-                    fn = self._build(unit_sel)
-                    if obs_trace.is_enabled():
-                        # time the lazy jit's synchronous first-call
-                        # trace+compile under a "compile" span; kernels
-                        # minted while tracing is off stay unwrapped
-                        fn = _timed_first_call(fn, "fused", unit_sel)
-                    self._jitted[unit_sel] = fn
-        g = self.g
         n = len(seed_eids)
+        with obs_trace.phase("schedule", stats, strat="fused", n_seeds=n):
+            fn = self._jitted.get(unit_sel)  # lock-free warm path
+            if fn is None:
+                with self._jit_lock:
+                    fn = self._jitted.get(unit_sel)
+                    if fn is None:
+                        fn = self._build(unit_sel)
+                        if obs_trace.is_enabled():
+                            # time the lazy jit's synchronous first-call
+                            # trace+compile under a "compile" span; kernels
+                            # minted while tracing is off stay unwrapped
+                            fn = _timed_first_call(fn, "fused", unit_sel)
+                        self._jitted[unit_sel] = fn
+            widths = executor.chunk_widths(n, self.batch_elem_cap, n_units)
+            if coalesce > 1:
+                widths = executor.coalesce_widths(widths, coalesce)
+        g = self.g
         if n == 0 or n_units == 0:
             return jax.device_put(jnp.zeros((n, n_units), jnp.int32), device)
         if dg is None:
             dg = self.dg
-        widths = executor.chunk_widths(n, self.batch_elem_cap, n_units)
-        if coalesce > 1:
-            widths = executor.coalesce_widths(widths, coalesce)
         total = sum(widths)
         # one padded staging buffer per field (padding only ever lands in
         # the tail chunk), one host→device transfer for the whole batch
-        with obs_trace.span(
-            "stage", stats=stats, strat="fused", n_seeds=n
-        ):
+        with obs_trace.phase("stage", stats, strat="fused", n_seeds=n):
             ss = np.full(total, -1, np.int32)
             dd = np.full(total, -1, np.int32)
             tt = np.zeros(total, np.int32)
@@ -330,8 +330,8 @@ class _FusedSeedPlan:
             tt[:n] = g.t[seed_eids]
             dev_s, dev_d, dev_t = jax.device_put((ss, dd, tt), device)
             stats["bytes_h2d"] += int(ss.nbytes + dd.nbytes + tt.nbytes)
-        with obs_trace.span(
-            "launch", stats=stats, strat="fused", n_chunks=len(widths)
+        with obs_trace.phase(
+            "dispatch", stats, strat="fused", n_chunks=len(widths)
         ):
             chunks = []
             s0 = 0
@@ -365,11 +365,12 @@ class _FusedSeedPlan:
         if n == 0 or len(unit_sel) == 0:
             return np.zeros((n, len(unit_sel)), dtype=np.int64)
         dev_out = self.launch_units(seed_eids, stats, unit_sel)
-        with obs_trace.span("gather", stats=stats, mode="fused"):
-            host = np.asarray(dev_out)  # THE one host sync of the fused pass
+        with obs_trace.phase("fetch", stats, mode="fused"):
+            with obs_trace.phase("wait", stats):
+                host = np.asarray(dev_out)  # THE one host sync of the fused pass
             stats["host_syncs"] += 1
             stats["bytes_d2h"] += int(host.nbytes)
-        return host[:n].astype(np.int64)
+            return host[:n].astype(np.int64)
 
     def assemble(
         self, key: str, unit_vals: np.ndarray, unit_sel: Tuple[int, ...]
@@ -398,7 +399,10 @@ class MiningResult:
     launches, padded elements, branch items, host syncs (exactly one per
     backend invocation — each compiled plan and the fused pass transfer
     their finished counts once), staging bytes h2d/d2h, new JIT traces,
-    and bucket-schedule cache hits.
+    bucket-schedule cache hits, and the host wall time of each phase of
+    the call (``schedule_ns``, ``stage_ns``, ``dispatch_ns``,
+    ``fetch_ns``, its blocking part ``wait_ns``, and the whole call's
+    ``mine_ns``).
 
     Sharded mines (``backend="sharded"``) additionally report per-shard
     observability: ``per_shard_seconds`` (per-shard dispatch wall,
@@ -671,26 +675,33 @@ class MiningSession:
                 names.append(spec.name)
         return names
 
-    def _mine_compiled(
-        self, names: List[str], seeds: np.ndarray
-    ) -> Tuple[np.ndarray, Dict[str, float], Tuple[str, ...], Dict[str, int]]:
+    def _mine_compiled(self, names: List[str], seeds: np.ndarray) -> MiningResult:
         """One compiled portfolio pass over `seeds`; shared-kernel columns
         are computed in a single fused launch group."""
-        self.compile()
         stats = executor.new_stats()
+        with obs_trace.phase("schedule", stats, step="portfolio"):
+            self.compile()
+            fused_cols = [
+                (j, n)
+                for j, n in enumerate(names)
+                if self._canon_of[n] in self._fused.emits
+            ]
+            if fused_cols:
+                unit_sel = self._fused.units_for(
+                    {self._canon_of[n] for _, n in fused_cols}
+                )
         out = np.zeros((len(seeds), len(names)), dtype=np.int64)
         seconds: Dict[str, float] = {}
-        fused_cols = [
-            (j, n) for j, n in enumerate(names) if self._canon_of[n] in self._fused.emits
-        ]
         if fused_cols:
-            unit_sel = self._fused.units_for({self._canon_of[n] for _, n in fused_cols})
             t0 = time.perf_counter()
             unit_vals = self._fused.mine_units(seeds, stats, unit_sel)
             dt = time.perf_counter() - t0
-            for j, n in fused_cols:
-                out[:, j] = self._fused.assemble(self._canon_of[n], unit_vals, unit_sel)
-                seconds[n] = dt  # shared fused-pass wall time (not additive)
+            with obs_trace.phase("fetch", stats, mode="assemble"):
+                for j, n in fused_cols:
+                    out[:, j] = self._fused.assemble(
+                        self._canon_of[n], unit_vals, unit_sel
+                    )
+                    seconds[n] = dt  # shared fused-pass wall time (not additive)
         done: Dict[str, Tuple[np.ndarray, float]] = {}
         for j, n in enumerate(names):
             key = self._canon_of[n]
@@ -705,9 +716,19 @@ class MiningSession:
                 for k in stats:
                     stats[k] += cp.stats[k] - before[k]
             out[:, j], seconds[n] = done[key]
+        with obs_trace.phase("fetch", stats, mode="result"):
+            res = MiningResult(
+                columns=tuple(names),
+                counts=out,
+                backend="compiled",
+                n_seeds=len(seeds),
+                seconds=seconds,
+                stats=stats,
+                fused=tuple(n for _, n in fused_cols),
+            )
         for k in stats:
             self.stats[k] += stats[k]
-        return out, seconds, tuple(n for _, n in fused_cols), stats
+        return res
 
     def _compiled_for(self, key: str) -> CompiledPattern:
         """A standalone compiled plan for a canonical key — the regular
@@ -798,26 +819,36 @@ class MiningSession:
                 "witnesses=k is a compiled-backend feature (device-side "
                 f"selection over the compare cubes); got backend={backend!r}"
             )
-        names = self._resolve_names(patterns)
         g = self.graph
         if seeds is None:
             seeds = np.arange(g.n_edges, dtype=np.int32)
         seeds = np.asarray(seeds, dtype=np.int32)
+        stats = executor.new_stats()
+        with obs_trace.phase("mine", stats, backend=backend, n_seeds=len(seeds)):
+            with obs_trace.phase("schedule", stats, step="names"):
+                names = self._resolve_names(patterns)
+            res = self._mine_backend(names, seeds, backend, n_parts, int(witnesses))
+        # the call's own phases: its whole wall and its name resolution
+        for k, v in stats.items():
+            res.stats[k] = res.stats.get(k, 0) + v
+            self.stats[k] += v
+        return res
 
+    def _mine_backend(
+        self,
+        names: List[str],
+        seeds: np.ndarray,
+        backend: str,
+        n_parts: Optional[int],
+        witnesses: int,
+    ) -> MiningResult:
+        """:meth:`mine` for resolved names and int32 seeds."""
+        g = self.graph
         if witnesses:
-            return self._mine_witnesses(names, seeds, int(witnesses))
+            return self._mine_witnesses(names, seeds, witnesses)
 
         if backend == "compiled":
-            counts, seconds, fused, stats = self._mine_compiled(names, seeds)
-            return MiningResult(
-                columns=tuple(names),
-                counts=counts,
-                backend=backend,
-                n_seeds=len(seeds),
-                seconds=seconds,
-                stats=stats,
-                fused=fused,
-            )
+            return self._mine_compiled(names, seeds)
 
         if backend == "oracle":
             from repro.core.oracle import GFPReference
@@ -884,15 +915,14 @@ class MiningSession:
             ids = plan.edge_ids[p][plan.valid[p]]
             rows = plan.positions[p][plan.valid[p]]
             t0 = time.perf_counter()
-            part_counts, part_seconds, fused, part_stats = self._mine_compiled(
-                names, ids
-            )
+            part = self._mine_compiled(names, ids)
             per_part.append(time.perf_counter() - t0)
-            counts[rows] = part_counts
+            counts[rows] = part.counts
+            fused = part.fused
             for n in names:
-                seconds[n] += part_seconds.get(n, 0.0)
+                seconds[n] += part.seconds.get(n, 0.0)
             for k in stats:
-                stats[k] += part_stats[k]
+                stats[k] += part.stats[k]
         return MiningResult(
             columns=tuple(names),
             counts=counts,
@@ -918,37 +948,39 @@ class MiningSession:
         from repro.core import shard
         from repro.graph.partition import partition_edges
 
-        self.compile()
-        if self._shard_ctx is None:
-            self._shard_ctx = shard.ShardContext(
-                self._dg, heartbeat_dir=self.shard_heartbeat_dir
-            )
-        ctx = self._shard_ctx
-        if n_parts is None:
-            n_parts = ctx.n_devices
-        plan = partition_edges(self.graph, n_parts, edge_ids=seeds)
-
-        fused_cols = [
-            (j, n) for j, n in enumerate(names) if self._canon_of[n] in self._fused.emits
-        ]
-        unit_sel: Tuple[int, ...] = ()
-        if fused_cols:
-            unit_sel = self._fused.units_for(
-                {self._canon_of[n] for _, n in fused_cols}
-            )
-        compiled_keys: List[str] = []
-        for n in names:
-            key = self._canon_of[n]
-            if key in self._compiled and key not in compiled_keys:
-                compiled_keys.append(key)
-                cp = self._compiled[key]
-                # keep every shard's schedule resident across mines —
-                # same slots+headroom sizing rule the streaming service
-                # applies to its portfolio schedule caches
-                cp.schedule_cache_cap = max(
-                    cp.schedule_cache_cap,
-                    schedule_cache_cap_for(plan.n_parts),
+        stats = executor.new_stats()
+        with obs_trace.phase("schedule", stats, step="partition"):
+            self.compile()
+            if self._shard_ctx is None:
+                self._shard_ctx = shard.ShardContext(
+                    self._dg, heartbeat_dir=self.shard_heartbeat_dir
                 )
+            ctx = self._shard_ctx
+            if n_parts is None:
+                n_parts = ctx.n_devices
+            plan = partition_edges(self.graph, n_parts, edge_ids=seeds)
+
+            fused_cols = [
+                (j, n) for j, n in enumerate(names) if self._canon_of[n] in self._fused.emits
+            ]
+            unit_sel: Tuple[int, ...] = ()
+            if fused_cols:
+                unit_sel = self._fused.units_for(
+                    {self._canon_of[n] for _, n in fused_cols}
+                )
+            compiled_keys: List[str] = []
+            for n in names:
+                key = self._canon_of[n]
+                if key in self._compiled and key not in compiled_keys:
+                    compiled_keys.append(key)
+                    cp = self._compiled[key]
+                    # keep every shard's schedule resident across mines —
+                    # same slots+headroom sizing rule the streaming service
+                    # applies to its portfolio schedule caches
+                    cp.schedule_cache_cap = max(
+                        cp.schedule_cache_cap,
+                        schedule_cache_cap_for(plan.n_parts),
+                    )
 
         coalesce = self.shard_coalesce
 
@@ -964,67 +996,67 @@ class MiningSession:
                 )
             return outs
 
-        stats = executor.new_stats()
         t0 = time.perf_counter()
         run = shard.run_sharded(plan, launch, ctx, stats)
         wall = time.perf_counter() - t0
-
-        counts = np.zeros((len(seeds), len(names)), dtype=np.int64)
-        if run.gather_mode == "collective":
-            # the device collective already reduced every shard's placed
-            # rows — each output is full-length in input order
-            host = run.host_outs
-            if fused_cols:
-                unit_vals = np.asarray(host["__fused__"], dtype=np.int64)
-                for j, n in fused_cols:
-                    counts[:, j] = self._fused.assemble(
-                        self._canon_of[n], unit_vals, unit_sel
-                    )
-            for j, n in enumerate(names):
-                key = self._canon_of[n]
-                if key in self._compiled:
-                    counts[:, j] = np.asarray(host[key], dtype=np.int64)
-        else:
-            # host gather: scatter each shard's ragged outputs through the
-            # plan's slot -> input-position map (duplicate seed ids land on
-            # their own rows)
-            for p in range(plan.n_parts):
-                rows = plan.positions[p][plan.valid[p]]
-                if len(rows) == 0:
-                    continue
-                out_p = run.host_outs[p]
+        with obs_trace.phase("fetch", stats, mode="assemble"):
+            counts = np.zeros((len(seeds), len(names)), dtype=np.int64)
+            if run.gather_mode == "collective":
+                # the device collective already reduced every shard's placed
+                # rows — each output is full-length in input order
+                host = run.host_outs
                 if fused_cols:
-                    unit_vals = np.asarray(out_p["__fused__"])[
-                        : len(rows)
-                    ].astype(np.int64)
+                    unit_vals = np.asarray(host["__fused__"], dtype=np.int64)
                     for j, n in fused_cols:
-                        counts[rows, j] = self._fused.assemble(
+                        counts[:, j] = self._fused.assemble(
                             self._canon_of[n], unit_vals, unit_sel
                         )
                 for j, n in enumerate(names):
                     key = self._canon_of[n]
                     if key in self._compiled:
-                        counts[rows, j] = np.asarray(out_p[key], dtype=np.int64)
+                        counts[:, j] = np.asarray(host[key], dtype=np.int64)
+            else:
+                # host gather: scatter each shard's ragged outputs through the
+                # plan's slot -> input-position map (duplicate seed ids land on
+                # their own rows)
+                for p in range(plan.n_parts):
+                    rows = plan.positions[p][plan.valid[p]]
+                    if len(rows) == 0:
+                        continue
+                    out_p = run.host_outs[p]
+                    if fused_cols:
+                        unit_vals = np.asarray(out_p["__fused__"])[
+                            : len(rows)
+                        ].astype(np.int64)
+                        for j, n in fused_cols:
+                            counts[rows, j] = self._fused.assemble(
+                                self._canon_of[n], unit_vals, unit_sel
+                            )
+                    for j, n in enumerate(names):
+                        key = self._canon_of[n]
+                        if key in self._compiled:
+                            counts[rows, j] = np.asarray(out_p[key], dtype=np.int64)
+            res = MiningResult(
+                columns=tuple(names),
+                counts=counts,
+                backend="sharded",
+                n_seeds=len(seeds),
+                # one shared device-parallel pass: every pattern reports the
+                # whole mine's wall (not additive across patterns or shards)
+                seconds={n: wall for n in names},
+                stats=stats,
+                fused=tuple(n for _, n in fused_cols),
+                partition_plan=plan,
+                per_shard_seconds=run.shard_walls,
+                shard_stats=run.shard_stats,
+                shard_devices=tuple(run.shard_devices),
+                dispatch_wall_s=run.dispatch_wall_s,
+                gather_mode=run.gather_mode,
+                worker_liveness=run.worker_liveness,
+            )
         for k in stats:
             self.stats[k] += stats[k]
-        return MiningResult(
-            columns=tuple(names),
-            counts=counts,
-            backend="sharded",
-            n_seeds=len(seeds),
-            # one shared device-parallel pass: every pattern reports the
-            # whole mine's wall (not additive across patterns or shards)
-            seconds={n: wall for n in names},
-            stats=stats,
-            fused=tuple(n for _, n in fused_cols),
-            partition_plan=plan,
-            per_shard_seconds=run.shard_walls,
-            shard_stats=run.shard_stats,
-            shard_devices=tuple(run.shard_devices),
-            dispatch_wall_s=run.dispatch_wall_s,
-            gather_mode=run.gather_mode,
-            worker_liveness=run.worker_liveness,
-        )
+        return res
 
     # -- streaming ------------------------------------------------------
     def service(

@@ -114,6 +114,10 @@ BUCKET_LADDER = (4, 16, 64, 256, 1024)
 BATCH_ELEM_CAP = 1 << 22  # max padded elements materialized per kernel call
 INVALID = np.int32(2**31 - 1)
 SEED_NAMES = ("seed.src", "seed.dst")
+# strategy code -> name: the cost model's three intersect lowerings, and
+# 3 for a plan with no intersect; names the device programs
+# (``mine_<strategy>``, ``witness_<strategy>``) in profiles
+STRATEGY_NAMES = ("bs1", "bs2", "pw", "count")
 # cost-model constants (relative op costs, calibrated on the CPU backend;
 # the ratio is what matters: one binary-search probe ≈ gather + compare)
 C_SEARCH_PER_ITER = 4.0 * 5.0  # 4 lower_bounds x gather-heavy iteration
@@ -1222,6 +1226,7 @@ class CompiledPattern:
             init = jnp.zeros(s.shape, jnp.int32)
             return jax.lax.fori_loop(0, n_sweep, step, init)
 
+        kernel.__name__ = kernel.__qualname__ = f"mine_{STRATEGY_NAMES[strat]}"
         return kernel
 
     def _kernel(
@@ -1601,13 +1606,7 @@ class CompiledPattern:
         family: ``schedule_hits`` under this mode gauges exactly the
         cross-tick reuse that keeps warm-tick ``trace_misses`` at zero.
         The LRU cap bounds the profile set a long-lived service pins."""
-        with obs_trace.span(
-            "schedule_build",
-            pattern=self.spec.name,
-            n_seeds=len(seed_eids),
-            mode="shape",
-        ):
-            sched = self._build_schedule(seed_eids, pad_rows=True)
+        sched = self._build_schedule(seed_eids, pad_rows=True)
         key = (
             "shape",
             sched.n_out,
@@ -1646,8 +1645,23 @@ class CompiledPattern:
         schedules stay value-keyed in both modes: their
         packed top-k payloads depend on exact seed order."""
         stats = self.stats if stats is None else stats
-        if self.schedule_mode == "shape" and not witness:
-            return self._schedule_shape_keyed(seed_eids, stats)
+        # the span's schedule_hits delta tells a lookup from a build
+        with obs_trace.phase(
+            "schedule",
+            stats,
+            pattern=self.spec.name,
+            n_seeds=len(seed_eids),
+            mode=self.schedule_mode,
+            witness=witness,
+        ):
+            if self.schedule_mode == "shape" and not witness:
+                return self._schedule_shape_keyed(seed_eids, stats)
+            return self._schedule_value_keyed(seed_eids, stats, witness)
+
+    def _schedule_value_keyed(
+        self, seed_eids: np.ndarray, stats: Dict[str, int], witness: bool
+    ) -> executor.Schedule:
+        """Value-keyed schedule path: cached on the seed ids themselves."""
         key = (
             len(seed_eids),
             hashlib.sha1(seed_eids.tobytes()).hexdigest(),
@@ -1663,13 +1677,7 @@ class CompiledPattern:
         # partitions' schedules concurrently (that concurrency is the whole
         # point of overlapped dispatch); keys differ across partitions so a
         # duplicated build is rare and benign — first insert wins.
-        with obs_trace.span(
-            "schedule_build",
-            pattern=self.spec.name,
-            n_seeds=len(seed_eids),
-            witness=witness,
-        ):
-            sched = self._build_schedule(seed_eids, witness=witness)
+        sched = self._build_schedule(seed_eids, witness=witness)
         with self._sched_lock:
             existing = self._schedules.get(key)
             if existing is not None:

@@ -52,15 +52,32 @@ cost of a host sync per launch; in this regime such a count would wrap.
 No realistic per-edge typology count approaches 2^31 — revisit with an
 int32 hi/lo pair if one ever does.)
 
-Tracing (`repro.obs.trace`, off by default): when the global tracer is
-enabled, each bucket group contributes a ``stage`` span (the staging
-``device_put``, with its ``bytes_h2d`` delta attached) and a ``launch``
-span (the chunk dispatch loop, with ``kernel_calls`` /
-``padded_elements`` deltas), and :func:`fetch` contributes a ``gather``
-span.  Spans time *dispatch*, not device completion — launches are
-asynchronous, so a closed ``launch`` span means work was submitted, and
-only the blocking ``gather`` span covers real device execution.  The
-tracer never adds a host sync; disabled, each span site is one branch.
+Phase counters (always on, one ``perf_counter_ns`` pair per phase
+through :func:`repro.obs.trace.phase`; nanoseconds of host wall time):
+
+``schedule_ns``       schedules built or looked up, the fused pass's
+                      preamble and name resolution
+``stage_ns``          staging buffers handed to the device (the fused
+                      pass also builds its seed buffers here; a
+                      compiled plan builds its own in ``schedule``)
+``dispatch_ns``       kernel launches enqueued (asynchronous: enqueue
+                      time, not device time)
+``fetch_ns``          the blocking read-back and the host work on its
+                      result (casts, assembly, the result object)
+``wait_ns``           the blocking part of ``fetch_ns`` alone
+``mine_ns``           the whole ``MiningSession.mine`` call
+
+Tracing (`repro.obs.trace`): each phase is also a span of the same
+name, in the JAX profiler's trace whenever a profiler session records
+and in the in-memory tracer when it is enabled.  Each bucket group
+contributes a ``stage`` span (the staging ``device_put``, with its
+``bytes_h2d`` delta attached) and a ``dispatch`` span (the chunk
+dispatch loop, with ``kernel_calls`` / ``padded_elements`` deltas),
+and :func:`fetch` contributes a ``fetch`` span around its ``wait``.
+Spans time *dispatch*, not device completion — launches are
+asynchronous, so a closed ``dispatch`` span means work was submitted,
+and only the blocking ``wait`` span covers real device execution.  The
+tracer never adds a host sync.
 """
 from __future__ import annotations
 
@@ -98,6 +115,13 @@ STAT_KEYS = (
     "bytes_d2h",
     "jit_cache_entries",
     "schedule_hits",
+    # phase counters (ns of host wall time; repro.obs.trace.phase)
+    "schedule_ns",
+    "stage_ns",
+    "dispatch_ns",
+    "fetch_ns",
+    "wait_ns",
+    "mine_ns",
 )
 
 MIN_CHUNK = 32  # smallest padded batch width (floor of the chunk ladder)
@@ -320,14 +344,12 @@ def execute(
     with jax.default_device(device):  # allocate the accumulator in place
         out = jnp.zeros(n_out, jnp.int32)
     for grp in groups:
-        with obs_trace.span(
-            "stage", stats=stats, strat=grp.strat, dims=str(grp.dims)
-        ):
+        with obs_trace.phase("stage", stats, strat=grp.strat, dims=str(grp.dims)):
             dev = jax.device_put(grp.staging, device)
             stats["bytes_h2d"] += sum(int(a.nbytes) for a in grp.staging)
         fn = kernel_for(grp.strat, grp.dims, grp.sweeps, grp.branch)
-        with obs_trace.span(
-            "launch", stats=stats, strat=grp.strat, dims=str(grp.dims)
+        with obs_trace.phase(
+            "dispatch", stats, strat=grp.strat, dims=str(grp.dims)
         ):
             s0 = 0
             for w in grp.widths:
@@ -346,8 +368,9 @@ def execute(
 
 def fetch(out_dev, stats: Dict[str, int]) -> np.ndarray:
     """THE host sync: one blocking transfer of the finished counts."""
-    with obs_trace.span("gather", stats=stats, mode="fetch"):
-        host = np.asarray(out_dev)
+    with obs_trace.phase("fetch", stats, mode="fetch"):
+        with obs_trace.phase("wait", stats):
+            host = np.asarray(out_dev)
         stats["host_syncs"] += 1
         stats["bytes_d2h"] += int(host.nbytes)
     return host

@@ -271,8 +271,9 @@ def gather(outs, stats: Dict[str, int], mode: str = "host"):
     pattern's device-resident count vector in this one call
     (``mode="portfolio"`` tags the span so trace tooling can tell the
     two apart)."""
-    with obs_trace.span("gather", stats=stats, mode=mode):
-        host = jax.device_get(outs)
+    with obs_trace.phase("fetch", stats, mode=mode):
+        with obs_trace.phase("wait", stats):
+            host = jax.device_get(outs)
         stats["host_syncs"] += 1
         stats["bytes_d2h"] += int(
             sum(a.nbytes for a in jax.tree_util.tree_leaves(host))
@@ -300,8 +301,8 @@ def collective_gather(placed, devices, stats: Dict[str, int]):
 
     from repro.launch.mesh import make_shard_mesh  # lazy: no import cycle
 
-    with obs_trace.span(
-        "gather", stats=stats, mode="collective", n_shards=len(placed)
+    with obs_trace.phase(
+        "fetch", stats, mode="collective", n_shards=len(placed)
     ):
         keys = list(placed[0])
         shapes = [placed[0][k].shape for k in keys]
@@ -314,7 +315,9 @@ def collective_gather(placed, devices, stats: Dict[str, int]):
         arr = jax.make_array_from_single_device_arrays(
             (len(placed),) + flat[0].shape[1:], sharding, flat
         )
-        host_flat = jax.device_get(_sum_shards_jit(arr))  # THE host sync
+        reduced = _sum_shards_jit(arr)
+        with obs_trace.phase("wait", stats):
+            host_flat = jax.device_get(reduced)  # THE host sync
         stats["host_syncs"] += 1
         stats["bytes_d2h"] += int(host_flat.nbytes)
     host = {}

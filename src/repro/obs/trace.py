@@ -1,30 +1,52 @@
 """`repro.obs.trace` — nested span tracer with Chrome trace-event export.
 
-A **span** is one timed region of the pipeline (``compile``,
-``schedule_build``, ``stage``, ``dispatch:shard3``, ``tick:mine``, ...)
-recorded with wall time, thread id, its parent span (per-thread nesting
-stack), free-form attributes, and optional **counter deltas**: pass
-``stats=some_dict`` and the numeric values of that dict are snapshotted
-at span entry and diffed at exit, so a ``dispatch:shard{k}`` span carries
-exactly the ``kernel_calls`` / ``bytes_h2d`` / ... it caused.
+A **span** is one timed region of the pipeline (``mine``, ``schedule``,
+``stage``, ``dispatch``, ``fetch``, ``dispatch:shard3``, ``tick:mine``,
+...) recorded with wall time, thread id, its parent span (per-thread
+nesting stack), free-form attributes, and optional **counter deltas**:
+pass ``stats=some_dict`` and the numeric values of that dict are
+snapshotted at span entry and diffed at exit, so a ``dispatch:shard{k}``
+span carries exactly the ``kernel_calls`` / ``bytes_h2d`` / ... it
+caused.
+
+Two clocks, one set of spans:
+
+* **The profiler's clock.**  While a JAX profiler session records
+  (``jax.profiler.start_trace`` / ``trace``), every span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name with its attributes
+  as metadata, so it lands on the ``/host:CPU`` plane of the
+  ``.xplane.pb`` beside the device's ops — enabled tracer or not.  The
+  check is the profiler's own ``TraceMe.is_enabled()`` flag, about
+  30 ns a span.
+* **The in-memory tracer** (:class:`Tracer`), off by default, keeps
+  spans on ``perf_counter_ns`` for the Chrome export and the summary.
+
+**Phase counters.**  :func:`phase` is a span that also adds its wall
+time to ``stats["<name>_ns"]``, always, with one ``perf_counter_ns``
+pair: the mine path's ``schedule`` / ``stage`` / ``dispatch`` /
+``fetch`` / ``wait`` / ``mine`` phases land in
+``MiningResult.stats`` as ``schedule_ns`` ... ``mine_ns`` (the
+``repro.core.executor.STAT_KEYS`` glossary) whether or not anything
+traces.
 
 Design constraints (this module is threaded through the mining hot
-paths — see ISSUE 9):
+paths):
 
-* **Off by default, near-zero disabled overhead.**  ``span()`` on a
-  disabled tracer is ONE branch returning a shared no-op context
-  manager — no allocation, no lock, no clock read.  The streaming bench
-  budget is < 2% p50 tick overhead with tracing disabled
-  (``tests/test_obs.py`` bounds it in a microbench-style unit test).
+* **Off by default, near-zero disabled overhead.**  ``span()`` with the
+  tracer disabled and no profiler session is one branch and one flag
+  read returning a shared no-op context manager — no allocation, no
+  lock, no clock read.  The streaming bench budget is < 2% p50 tick
+  overhead with tracing disabled (``tests/test_obs.py`` bounds it in a
+  microbench-style unit test).
 * **Thread-safe.**  The sharded dispatch pool enters spans from one
   worker thread per device concurrently; the nesting stack is
   thread-local and finished spans append to a lock-guarded list.
 * **No host syncs.**  Spans time *dispatch*, not device completion: JAX
-  launches are asynchronous, so a ``dispatch:shard{k}`` span closing
-  means the shard's launches were *submitted*, not that the device
-  finished them.  Device execution overlaps later spans (that overlap
-  is exactly what the trace view shows); only the ``gather`` span ends
-  after real device work, because the fetch blocks.  The tracer itself
+  launches are asynchronous, so a ``dispatch`` span closing means the
+  launches were *submitted*, not that the device finished them.  Device
+  execution overlaps later spans (that overlap is exactly what the
+  trace view shows); only the ``wait`` span inside ``fetch`` covers
+  real device work, because the read-back blocks.  The tracer itself
   never touches a device array.
 
 Exports:
@@ -52,6 +74,9 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax._src.lib import _profiler
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span",
     "Tracer",
@@ -61,7 +86,11 @@ __all__ = [
     "disable",
     "is_enabled",
     "span",
+    "phase",
 ]
+
+# a JAX profiler session is recording: its own flag, about 30 ns a read
+_profiling = _profiler.TraceMe.is_enabled
 
 
 class _NoopSpan:
@@ -90,6 +119,32 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _ProfilerSpan:
+    """A span only the profiler records: the tracer is disabled, but a
+    profiler session is on, so the span is a ``TraceAnnotation`` alone."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, attrs: dict):
+        self._ann = TraceAnnotation(name, **attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs) -> "_ProfilerSpan":
+        self._ann.set_metadata(**attrs)
+        return self
+
+    @property
+    def span_id(self) -> Optional[int]:
+        return None
+
+
 class Span:
     """One live span: records itself into the tracer on ``__exit__``."""
 
@@ -103,6 +158,7 @@ class Span:
         "parent_id",
         "tid",
         "t0_ns",
+        "_ann",
     )
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict, stats):
@@ -119,10 +175,13 @@ class Span:
         self.parent_id = None
         self.tid = 0
         self.t0_ns = 0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes mid-span (chainable)."""
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
@@ -132,11 +191,16 @@ class Span:
         stack = tr._stack()
         self.parent_id = stack[-1] if stack else None
         stack.append(self.span_id)
+        if _profiling():
+            self._ann = TraceAnnotation(self.name, **self.attrs)
+            self._ann.__enter__()
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         tr = self.tracer
         stack = tr._stack()
         if stack and stack[-1] == self.span_id:
@@ -198,8 +262,10 @@ class Tracer:
 
     def span(self, name: str, *, stats: Optional[dict] = None, **attrs):
         """A context manager timing ``name``.  THE hot-path call: one
-        branch when disabled."""
+        branch and the profiler's flag when disabled."""
         if not self.enabled:
+            if _profiling():
+                return _ProfilerSpan(name, attrs)
             return _NOOP
         return Span(self, name, attrs, stats)
 
@@ -348,6 +414,36 @@ def is_enabled() -> bool:
 
 def span(name: str, *, stats: Optional[dict] = None, **attrs):
     """Module-level convenience: a span on the global tracer.  This is
-    the call sites' entry point — when tracing is disabled it costs one
-    global load, one attribute branch, and the shared no-op manager."""
+    the call sites' entry point — when tracing is disabled and no
+    profiler session records it costs one global load, one attribute
+    branch, the profiler's flag, and the shared no-op manager."""
     return _TRACER.span(name, stats=stats, **attrs)
+
+
+class _Phase:
+    """A span that also adds its wall time to ``stats[key]``."""
+
+    __slots__ = ("_span", "_stats", "_key", "_t0")
+
+    def __init__(self, sp, stats: dict, key: str):
+        self._span = sp
+        self._stats = stats
+        self._key = key
+        self._t0 = 0
+
+    def __enter__(self):
+        sp = self._span.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return sp
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter_ns() - self._t0
+        self._stats[self._key] = self._stats.get(self._key, 0) + dt
+        return self._span.__exit__(*exc)
+
+
+def phase(name: str, stats: dict, **attrs):
+    """A phase of the mine path: :func:`span` ``name`` (with ``stats``
+    deltas), whose wall time is also added to ``stats[name + "_ns"]``
+    — always, traced or not, at one ``perf_counter_ns`` pair."""
+    return _Phase(_TRACER.span(name, stats=stats, **attrs), stats, name + "_ns")

@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import executor, ops
-from repro.core.compiler import _I32_MAX, INVALID, _graph_rows
+from repro.core.compiler import _I32_MAX, INVALID, STRATEGY_NAMES, _graph_rows
 from repro.core.spec import NEG_INF, POS_INF, Neigh, NodeRef, SetExpr, Stage, StageT, TimeBound, _SeedT
 from repro.graph.csr import DeviceGraph
 from repro.witness import Witnesses, witness_layout
@@ -446,6 +446,7 @@ def _build_witness_kernel(
         tot, _, packed = jax.lax.fori_loop(0, n_sweep, step, init)
         return tot, packed
 
+    kernel.__name__ = kernel.__qualname__ = f"witness_{STRATEGY_NAMES[strat]}"
     return kernel
 
 

@@ -154,15 +154,20 @@ def test_tracer_capacity_drops_oldest(tracer):
 
 
 def test_tracer_thread_lanes(tracer):
+    # all four workers stay alive until each has opened its span: a
+    # worker that exits early frees its OS thread id for the next one
+    together = threading.Barrier(4)
+
     def worker(k):
         with tracer.span(f"w{k}"):
-            time.sleep(0.001)
+            together.wait(timeout=30)
 
     threads = [threading.Thread(target=worker, args=(k,)) for k in range(4)]
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
     spans = tracer.spans()
     assert len(spans) == 4
     assert all(ev["parent"] is None for ev in spans)  # per-thread stacks
@@ -360,7 +365,7 @@ def test_streaming_20_ticks_trace_and_flight(tracer, registry, tmp_path, caplog)
     # stage spans nest under the tick:mine stage, carrying counter deltas
     mines = [ev for ev in spans if ev["name"] == "tick:mine"]
     assert any(ev["attrs"].get("kernel_calls", 0) > 0 for ev in mines)
-    launches = [ev for ev in spans if ev["name"] == "launch"]
+    launches = [ev for ev in spans if ev["name"] == "dispatch"]
     assert launches and all(
         by_id[ev["parent"]]["name"] in ("tick:mine", "tick:witness")
         or by_id[by_id[ev["parent"]]["parent"]]["name"]
@@ -497,7 +502,7 @@ print(json.dumps({
     ),
     "mine_kernel_calls": int(res.stats["kernel_calls"]),
     "gather_modes": sorted(
-        e["args"].get("mode", "") for e in evs if e["name"] == "gather"
+        e["args"].get("mode", "") for e in evs if e["name"] == "fetch"
     ),
     "beat_metrics": sum(
         1
@@ -538,14 +543,15 @@ def test_sharded_trace_multi_device_subprocess(tmp_path):
     assert got["dispatch_spans"] == [f"dispatch:shard{k}" for k in range(8)]
     # per-shard span counter deltas reassemble the mine-level total
     assert got["shard_kernel_calls"] == got["mine_kernel_calls"]
-    assert got["gather_modes"] == ["collective"]
+    # one collective read-back, then the host assembles the result
+    assert got["gather_modes"] == ["assemble", "collective"]
     assert got["beat_metrics"] == 8  # one liveness gauge per device
 
     with open(trace_path) as f:
         trace = json.load(f)
     assert {e["name"] for e in trace["traceEvents"]} >= {
         "dispatch:shard0",
-        "gather",
+        "fetch",
         "stage",
-        "launch",
+        "dispatch",
     }
