@@ -243,12 +243,12 @@ class _FusedSeedPlan:
                 u = bound(st.window.until, t)
                 if st.op == "count_window":
                     if st.operand.direction == "out":
-                        indptr, t_sorted = dg.out_indptr, dg.out_t_sorted
+                        indptr, index = dg.out_indptr, dg.out_t_index
                     else:
-                        indptr, t_sorted = dg.in_indptr, dg.in_t_sorted
+                        indptr, index = dg.in_indptr, dg.in_t_index
                     cols.append(
-                        ops.count_window(
-                            t_sorted, indptr, env[st.operand.node.name], a, u, n_iters
+                        ops.count_window_tiled(
+                            index, indptr, env[st.operand.node.name], a, u
                         )
                     )
                 else:  # count_edges between two bound seed endpoints
@@ -268,6 +268,21 @@ class _FusedSeedPlan:
 
         kernel.__name__ = kernel.__qualname__ = "fused_seed_local"
         return jax.jit(kernel)
+
+    def search_rounds(self, unit_sel: Tuple[int, ...], dg) -> int:
+        """Dependent gather rounds of one launch's searches: a windowed
+        degree's two tiled searches take one row probe per index level,
+        a seed-edge multiplicity's four binary searches ``n_iters``
+        scalar probes each."""
+        rounds = 0
+        for i in unit_sel:
+            st = self._unit_stages[i]
+            if st.op == "count_window":
+                out = st.operand.direction == "out"
+                rounds += 2 * (len(dg.out_t_index if out else dg.in_t_index) - 1)
+            else:
+                rounds += 4 * self.n_iters
+        return rounds
 
     # -- execution ------------------------------------------------------
     def launch_units(
@@ -335,11 +350,13 @@ class _FusedSeedPlan:
         ):
             chunks = []
             s0 = 0
+            rounds = self.search_rounds(unit_sel, dg)
             for w in widths:
                 sl = slice(s0, s0 + w)
                 chunks.append(fn(dg, dev_s[sl], dev_d[sl], dev_t[sl]))
                 stats["kernel_calls"] += 1
                 stats["padded_elements"] += w * n_units
+                stats["search_rounds"] += rounds
                 s0 += w
             return chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks)
 

@@ -42,6 +42,11 @@ Observability counters (reported through ``CompiledPattern.stats`` /
 ``schedule_hits``     bucket schedules served from the schedule cache
                       (repeated ``mine()`` calls skip the host-side
                       numpy grouping entirely)
+``search_rounds``     dependent gather rounds of the fused seed-local
+                      pass's searches, summed over launches: a tiled
+                      window search probes one 128-lane row per index
+                      level (2 on graphs up to 67M transfers), a binary
+                      search one scalar per iteration
 
 Accumulation width: device arrays are int32 across the system (JAX x64
 stays off — see ``TemporalGraph.to_device``), so the device-resident
@@ -115,6 +120,7 @@ STAT_KEYS = (
     "bytes_d2h",
     "jit_cache_entries",
     "schedule_hits",
+    "search_rounds",
     # phase counters (ns of host wall time; repro.obs.trace.phase)
     "schedule_ns",
     "stage_ns",

@@ -10,6 +10,10 @@ TPU adaptation of the paper's warp-cooperative kernels:
   sorted by (id, t), so the run is time-sorted).  This replaces the int64
   composite-key search with pure int32 ops (TPU-friendly).
 * ``count_window`` — windowed degree on the time-sorted row copy.
+* ``count_window_tiled`` — the same count through a tiled search of the
+  whole time-sorted flat (:func:`repro.graph.csr.time_index`): one
+  broadcast compare and one 128-lane row gather per level, no loop.
+  Only the fused seed-local kernel uses it; its queries are ``(B,)``.
 * ``expand`` — padded neighborhood materialization for ``for_all`` stages
   (the only primitive that materializes; intersections never do).
 
@@ -32,6 +36,8 @@ __all__ = [
     "count_id_in_window_pos",
     "count_window",
     "count_window_pos",
+    "count_window_tiled",
+    "tiled_lower_bound",
     "expand",
     "expand_pos",
     "dedup_ids",
@@ -163,6 +169,58 @@ def count_window_pos(t_sorted_flat, indptr, node, after, until, n_iters: int):
     end = indptr[safe + 1]
     cnt, pos = count_t_in_pos(t_sorted_flat, start, end, after, until, n_iters)
     return jnp.where(node >= 0, cnt, 0), pos
+
+
+def tiled_lower_bound(index, start, end, q):
+    """Lower bound of q in the time-sorted run flat[start:end), as an
+    absolute flat position, through the tiled index of the whole flat.
+
+    ``index`` is :func:`repro.graph.csr.time_index`'s ``(top, ...,
+    leaf)``: level ``j`` from the leaf up samples every ``tile**j``-th
+    element of the flat, in rows of ``tile``; ``top`` is the coarsest
+    sample, 1-D.  The predicate ``p < start or (p < end and flat[p] <
+    q)`` over flat positions ``p`` is monotone (true exactly below the
+    run's lower bound), so its count of true positions IS the lower
+    bound: the top samples are compared all at once, then each level
+    gathers the one row of ``tile`` samples that holds the last true
+    position and counts inside it.  Positions past ``end`` never count,
+    so the index's padding is never read as data.  Dependent gather
+    rounds: ``len(index) - 1``."""
+    start, end, q = jnp.broadcast_arrays(
+        jnp.asarray(start, jnp.int32),
+        jnp.asarray(end, jnp.int32),
+        jnp.asarray(q, jnp.int32),
+    )
+    start, end, q = start[..., None], end[..., None], q[..., None]
+
+    def n_true(pos, vals):
+        hit = (pos < start) | ((pos < end) & (vals < q))
+        return jnp.sum(hit, axis=-1, dtype=jnp.int32)
+
+    top, rows = index[0], index[1:]
+    tile = rows[-1].shape[1]
+    stride = tile ** len(rows)
+    lane = jnp.arange(tile, dtype=jnp.int32)
+    lb = n_true(jnp.arange(top.shape[0], dtype=jnp.int32) * stride, top)
+    for level in rows:  # coarse to fine: one row of `tile` samples each
+        blk = jnp.maximum(lb - 1, 0)  # the block holding the last true p
+        stride //= tile
+        pos = (blk * (stride * tile))[..., None] + lane * stride
+        lb = blk * tile + n_true(pos, level[blk])
+    return lb
+
+
+def count_window_tiled(index, indptr, node, after, until):
+    """:func:`count_window` through :func:`tiled_lower_bound`: the same
+    windowed degree (clamped at 0, 0 for ``node < 0``) on the index of
+    the time-sorted row copy."""
+    node = jnp.asarray(node, jnp.int32)
+    safe = jnp.maximum(node, 0)
+    start = indptr[safe]
+    end = indptr[safe + 1]
+    a = tiled_lower_bound(index, start, end, jnp.asarray(after, jnp.int32) + 1)
+    b = tiled_lower_bound(index, start, end, jnp.asarray(until, jnp.int32) + 1)
+    return jnp.where(node >= 0, jnp.maximum(b - a, 0), 0)
 
 
 def dedup_ids(ids, ts, mask, invalid):

@@ -28,7 +28,18 @@ __all__ = [
     "DeviceGraph",
     "build_temporal_graph",
     "csr_row_offsets",
+    "time_index",
+    "time_index_shapes",
 ]
+
+# Lane width: the tiled time search (repro.core.ops.tiled_lower_bound)
+# probes one row of TILE samples per level.
+TILE = 128
+# The top level of a time index is compared whole, so it holds at most
+# this many rows' worth of samples (4,096 at TILE; HI-Medium's 32M
+# transfers need 1,950).
+_TOP_ROWS = 32
+_INT32_MAX = np.iinfo(np.int32).max
 
 
 def _pow2ceil(x: int) -> int:
@@ -45,6 +56,43 @@ def csr_row_offsets(indptr: np.ndarray, nodes: np.ndarray):
     first = np.repeat(np.cumsum(lens) - lens, lens)
     offs = np.repeat(starts, lens) + (np.arange(tot, dtype=np.int64) - first)
     return offs, lens
+
+
+def _index_levels(n: int, tile: int) -> int:
+    """Row levels of a time index over ``n`` elements: at least two (one
+    search shape from a stream tick up to HI-Medium), more only where
+    the top level would pass ``_TOP_ROWS`` rows."""
+    levels = 2
+    while -(-n // tile**levels) > _TOP_ROWS * tile:
+        levels += 1
+    return levels
+
+
+def time_index_shapes(n: int, tile: int = TILE):
+    """Shapes of :func:`time_index`'s arrays for a flat of ``n``."""
+    levels = _index_levels(n, tile)
+    n_pad = max(1, -(-n // tile**levels)) * tile**levels
+    return [(n_pad // tile**levels,)] + [
+        (n_pad // tile ** (j + 1), tile) for j in range(levels - 1, -1, -1)
+    ]
+
+
+def time_index(flat: np.ndarray, tile: int = TILE):
+    """Tiled search index of a time-sorted CSR flat, coarse to fine: the
+    1-D top sample, then each level's samples in rows of ``tile``, the
+    last being the flat itself as ``(n_pad / tile, tile)``.  The flat is
+    padded with INT32_MAX (above every time) to a multiple of the top
+    stride; levels are strided slices of it, so nothing is searched on
+    the host.  Kept 2-D so the kernel gathers whole lane rows."""
+    shapes = time_index_shapes(len(flat), tile)
+    leaf = np.full(shapes[-1][0] * tile, _INT32_MAX, np.int32)
+    leaf[: len(flat)] = flat
+    stride = leaf.size // shapes[0][0]
+    out = [leaf[::stride]]
+    for shape in shapes[1:]:
+        stride //= tile
+        out.append(np.ascontiguousarray(leaf[::stride]).reshape(shape))
+    return tuple(out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,7 +186,10 @@ class TemporalGraph:
         trace family a later, bigger tick would have to remint.
         Oversizing is exact: padded CSR tails sit above every real
         ``indptr`` value and extra bisection iterations converge
-        harmlessly."""
+        harmlessly.
+
+        Each time-sorted flat also gets its :func:`time_index`, built from
+        the flat as mirrored, so its shapes follow ``e_pad`` in pad mode."""
         import jax.numpy as jnp
 
         def pad_edges(a: np.ndarray, fill: int, e_pad: int) -> np.ndarray:
@@ -164,6 +215,7 @@ class TemporalGraph:
             max_deg = max(1, self.max_out_deg(), self.max_in_deg())
 
         i32 = lambda a: jnp.asarray(a, dtype=jnp.int32)
+        index = lambda a: tuple(jnp.asarray(x) for x in time_index(ep(a, 0)))
         return DeviceGraph(
             n_nodes=n_nodes,
             n_edges=n_edges,
@@ -184,6 +236,8 @@ class TemporalGraph:
             in_eid=i32(ep(self.in_eid, -1)),
             in_t_sorted=i32(ep(self.in_t_sorted, 0)),
             in_eid_t=i32(ep(self.in_eid_t, -1)),
+            out_t_index=index(self.out_t_sorted),
+            in_t_index=index(self.in_t_sorted),
         )
 
 
@@ -210,10 +264,17 @@ class DeviceGraph:
     in_eid: "object"
     in_t_sorted: "object"
     in_eid_t: "object"
+    # time_index of out_t_sorted / in_t_sorted (fused seed-local kernel)
+    out_t_index: tuple
+    in_t_index: tuple
 
     def arrays(self) -> dict:
-        d = dataclasses.asdict(self)
-        return {k: v for k, v in d.items() if not isinstance(v, int)}
+        """The 1-D device arrays by field name (the indexes left out)."""
+        return {
+            f.name: v
+            for f in dataclasses.fields(self)
+            if not isinstance(v := getattr(self, f.name), (int, tuple))
+        }
 
 
 def _register_devicegraph_pytree() -> None:

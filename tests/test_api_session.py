@@ -82,6 +82,44 @@ def test_backends_agree(dense_graph):
         session.mine(backend="nope")
 
 
+def test_fused_mine_hub_rows_past_top_stride_oracle_exact():
+    """The fused seed-local kernel's tiled search on rows of more than
+    16,384 transfers (an in-hub and an out-hub), so the index's top
+    level picks the block: counts equal the oracle's, and every window
+    search takes two row probes."""
+    from repro.graph.csr import build_temporal_graph
+
+    rng = np.random.default_rng(5)
+    hub = 17_000
+    src = np.concatenate(
+        [rng.integers(2, 64, hub), np.ones(hub, np.int64), rng.integers(0, 64, 3000)]
+    )
+    dst = np.concatenate(
+        [np.zeros(hub, np.int64), rng.integers(2, 64, hub), rng.integers(0, 64, 3000)]
+    )
+    dst[src == dst] = (dst[src == dst] + 1) % 64
+    t = rng.integers(0, 20_000, len(src))
+    g = build_temporal_graph(src, dst, t, n_nodes=64)
+    names = ["fan_in", "fan_out", "deg_in", "deg_out"]
+    session = MiningSession(g, window=256).register(*names)
+    seeds = np.concatenate(
+        [
+            rng.choice(hub, 24, replace=False),  # into the in-hub
+            hub + rng.choice(hub, 24, replace=False),  # out of the out-hub
+            np.flatnonzero((src[2 * hub :] <= 1) | (dst[2 * hub :] <= 1))[:24]
+            + 2 * hub,  # the hubs on the other side
+            2 * hub + rng.choice(3000, 24, replace=False),
+        ]
+    ).astype(np.int32)
+    res = session.mine(seeds=seeds)
+    assert set(res.fused) == set(names)
+    orc = session.mine(seeds=seeds, backend="oracle")
+    np.testing.assert_array_equal(res.counts, orc.counts)
+    assert res.counts[:24, 0].min() > 100 and res.counts[24:48, 1].min() > 100
+    n_searches = 2 * session._fused.n_units * res.stats["kernel_calls"]
+    assert res.stats["search_rounds"] == 2 * n_searches
+
+
 def test_seed_subset_and_result_accessors(dense_graph):
     session = MiningSession(dense_graph, window=W).register("fan_in", "cycle3")
     seeds = np.array([3, 0, 17, 5], dtype=np.int32)

@@ -1,8 +1,10 @@
 import numpy as np
 import jax.numpy as jnp
+import pytest
 from tests.hypothesis_compat import given, settings, st
 
 from repro.core import ops
+from repro.graph.csr import time_index
 
 
 @settings(max_examples=40, deadline=None)
@@ -81,3 +83,78 @@ def test_expand_mask_and_offset():
     # offset sweeps the tail of row 2 (len 4): offset 4 -> nothing left
     mask2, _ = ops.expand(indptr, (flat,), node, 4, offset=4)
     assert not np.asarray(mask2)[2].any()
+
+
+def _tiled_csr(rng, tile):
+    """Time-sorted CSR rows for the tiled search: empty rows, rows that
+    cross tile and sample boundaries, one row longer than tile**2, five
+    trailing isolated nodes, and a flat whose length is no multiple of
+    the tile."""
+    lens = rng.integers(0, 6 * tile, 48)
+    lens[rng.choice(48, 8, replace=False)] = 0
+    lens[17] = tile * tile + 3
+    lens = np.concatenate([lens, np.zeros(5, np.int64)])
+    if lens.sum() % tile == 0:
+        lens[3] += 1
+    indptr = np.concatenate([[0], np.cumsum(lens)]).astype(np.int32)
+    flat = np.concatenate(
+        [np.sort(rng.integers(0, 1000, n)) for n in lens]
+    ).astype(np.int32)
+    return indptr, flat
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("tile", [2, 4, 128])
+def test_count_window_tiled_matches_binary_search_and_brute(tile, seed):
+    rng = np.random.default_rng(seed)
+    indptr, flat = _tiled_csr(rng, tile)
+    index = time_index(flat, tile)
+    assert len(index) >= 3 and index[-1].shape[1] == tile
+    assert len(flat) % tile != 0 and int(np.diff(indptr).max()) > tile * tile
+    n_nodes = len(indptr) - 1
+    q = 400
+    node = rng.integers(-1, n_nodes, q).astype(np.int32)
+    node[:4] = [-1, 17, n_nodes - 1, 17]
+    after = rng.integers(-50, 1100, q).astype(np.int32)
+    until = (after + rng.integers(-20, 300, q)).astype(np.int32)
+    # windows wholly before and wholly after every row
+    after[4:8], until[4:8] = -100, -1
+    after[8:12], until[8:12] = 1000, 2000
+    node[4:12] = [17, 0, 1, 2, 17, 0, 1, 2]
+
+    got = ops.count_window_tiled(
+        tuple(jnp.asarray(x) for x in index),
+        jnp.asarray(indptr),
+        jnp.asarray(node),
+        jnp.asarray(after),
+        jnp.asarray(until),
+    )
+    binary = ops.count_window(
+        jnp.asarray(flat),
+        jnp.asarray(indptr),
+        jnp.asarray(node),
+        jnp.asarray(after),
+        jnp.asarray(until),
+        ops.n_iters_for(int(np.diff(indptr).max())),
+    )
+    want = []
+    for nd, a, u in zip(node, after, until):
+        row = flat[indptr[max(nd, 0)] : indptr[max(nd, 0) + 1]]
+        want.append(0 if nd < 0 else int(np.sum((row > a) & (row <= u))))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(binary))
+
+    # the lower bound itself, as an absolute flat position
+    safe = np.maximum(node, 0)
+    start, end = indptr[safe], indptr[safe + 1]
+    lb = ops.tiled_lower_bound(
+        tuple(jnp.asarray(x) for x in index),
+        jnp.asarray(start),
+        jnp.asarray(end),
+        jnp.asarray(after),
+    )
+    want_lb = [
+        s + np.searchsorted(flat[s:e], v, side="left")
+        for s, e, v in zip(start, end, after)
+    ]
+    np.testing.assert_array_equal(np.asarray(lb), want_lb)

@@ -10,6 +10,7 @@ imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +18,10 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.api import MiningSession
 from repro.core.compiler import BUCKET_LADDER, CompiledPattern
 from repro.core.patterns import build_pattern
-from repro.graph.csr import DeviceGraph
+from repro.graph.csr import DeviceGraph, time_index_shapes
 from repro.kernels.intersect_count import intersect_count
 from tests.conftest import random_temporal_graph
 
@@ -105,22 +107,53 @@ def test_witness_hub_branch_kernel_compiles(one_chip, no_persistent_cache):
     _compile_at_hi_small(kernel, g, dims, one_chip)
 
 
-def _compile_at_hi_small(kernel, g, dims, one_chip):
-    n_nodes, n_edges = 1 << 19, 1 << 23
-    small = g.to_device()
-    dg = DeviceGraph(
+def _device_graph_at(g, n_nodes, n_edges, max_deg, one_chip):
+    """Shapes of ``g``'s device mirror at another graph size."""
+    index = tuple(_sds(s, one_chip) for s in time_index_shapes(n_edges))
+    return DeviceGraph(
         n_nodes=n_nodes,
         n_edges=n_edges,
-        max_deg=small.max_deg,
+        max_deg=max_deg,
         **{
             k: _sds(
                 (n_nodes + 1,) if k.endswith("indptr") else (n_edges,),
                 one_chip,
                 v.dtype,
             )
-            for k, v in small.arrays().items()
+            for k, v in g.to_device().arrays().items()
         },
+        out_t_index=index,
+        in_t_index=index,
     )
+
+
+def test_fused_seed_local_kernel_has_no_loop(one_chip, no_persistent_cache):
+    """The fused seed-local kernel at HI-Small's published size (515,088
+    accounts, 5,078,345 transfers, 8,192 seeds a launch): its windowed
+    degrees search without a ``while`` loop, each probe a gather of one
+    128-lane row, and the 2-D leaf is read in place — never relaid out
+    as a 1-D flat (a cross-program prefetch keeps its tiled layout)."""
+    rng = np.random.default_rng(0)
+    g = random_temporal_graph(rng, n_nodes=32, n_edges=256, t_max=4096)
+    names = ("fan_in", "fan_out", "deg_in", "deg_out")
+    fused = MiningSession(g, window=4096).register(*names).compile()._fused
+    kernel = fused._build(tuple(range(fused.n_units)))
+    n_edges = 5_078_345
+    dg = _device_graph_at(g, 515_088, n_edges, 340_767, one_chip)
+    s = _sds((8192,), one_chip)
+    hlo = kernel.lower(dg, s, s, s).compile().as_text()
+    assert " while(" not in hlo
+    n_rows, tile = time_index_shapes(n_edges)[-1]
+    assert re.findall(r"= s32\[8192,128\]\S* gather\(", hlo)
+    probes = re.findall(r"gather\(.*slice_sizes=\{([0-9,]+)\}", hlo)
+    assert probes.count("1,128") == 2 * 2 * fused.n_units
+    assert probes.count("1") == 2 * fused.n_units  # the indptr reads
+    assert f"s32[{n_rows * tile}]" not in hlo
+    assert not re.search(rf"= s32\[{n_rows},{tile}\]\S* copy\(", hlo)
+
+
+def _compile_at_hi_small(kernel, g, dims, one_chip):
+    dg = _device_graph_at(g, 1 << 19, 1 << 23, g.to_device().max_deg, one_chip)
     b = (1 << 22) // int(np.prod(dims))
     s = _sds((b,), one_chip)
     compiled = kernel.lower(dg, s, s, s, s, s).compile()
